@@ -361,12 +361,14 @@ def bench_precision(precision="bf16"):
 def bench_dist():
     """Sequential-vs-concurrent stage ticks (repro.dist) under 8 forced
     host devices — in a subprocess, because the device count is fixed at
-    first backend touch and this process must stay single-device."""
+    first backend touch and this process must stay single-device.  The
+    child always runs with ``JAX_PLATFORMS=cpu``: it measures forced host
+    devices, and on a TPU host this process already holds the chip."""
     import subprocess
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src") + os.pathsep \
         + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-m", "repro.dist.bench"],
                          capture_output=True, text=True, env=env)
     if out.returncode != 0:

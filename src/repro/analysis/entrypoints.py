@@ -9,7 +9,7 @@ exist only to drive ``jax.make_jaxpr``.
 Everything is built under ``runtime.assume_donation()``: the CPU hosts that
 run the analyzer can't *execute* donation, but the jitted steps read
 ``donate_argnums`` at wrap time, and tracing only needs the requested masks
-to land in the jaxpr's pjit params.  That env contract (REPRO_ASSUME_DONATION)
+to land in the jaxpr's jit params.  That env contract (REPRO_ASSUME_DONATION)
 is exactly what makes the donation-coverage rule meaningful off-TPU.
 
 Arch routing: MLP configs (paper_mlp) get the MLP epoch steps; LM configs

@@ -21,9 +21,10 @@ lowering, not the docs):
   through adds legitimately (a bf16 param update), and the decode cache's
   bf16 carry feeds ``dynamic_update_slice``, not ``add``, staying silent.
 * Host transfers inside a jitted region surface as callback primitives
-  (``debug_callback`` / ``pure_callback`` / ``io_callback``); a plain
-  ``jax.debug.print`` in a scan body is the classic accidental one.
-* ``donated_invars`` lives on the top-level pjit equation's params,
+  (``debug_print`` / ``debug_callback`` / ``pure_callback`` /
+  ``io_callback``); a plain ``jax.debug.print`` in a scan body is the
+  classic accidental one.
+* ``donated_invars`` lives on the top-level jit equation's params,
   leaf-expanded in argument order — comparing it against the donation the
   call site *requested* catches donation silently dropped by a wrapper.
 """
@@ -38,8 +39,8 @@ from repro.analysis.core import AnalysisContext, Finding, register
 from repro.analysis.entrypoints import artifacts
 from repro.analysis.trace import donated_invars, iter_eqns, leaf_counts
 
-HOST_CALLBACK_PRIMS = ("debug_callback", "pure_callback", "io_callback",
-                       "callback")
+HOST_CALLBACK_PRIMS = ("debug_print", "debug_callback", "pure_callback",
+                       "io_callback", "callback")
 REDUCE_PRIMS = ("reduce_sum", "cumsum", "add_any", "reduce_window_sum")
 LOW_PRECISION = (jnp.bfloat16, jnp.float16)
 
@@ -213,7 +214,7 @@ def donation(ctx: AnalysisContext) -> List[Finding]:
           "dependent Python branches) and are single jitted programs.",
           tags=("trace",))
 def recompile_hazard(ctx: AnalysisContext) -> List[Finding]:
-    from repro.analysis.trace import top_pjit_eqn
+    from repro.analysis.trace import top_jit_eqn
     out = []
     for name, art in artifacts(ctx).items():
         if art.error is not None:
@@ -223,7 +224,7 @@ def recompile_hazard(ctx: AnalysisContext) -> List[Finding]:
                         "arg or data-dependent Python control flow?)",
                 evidence={"error": art.error.splitlines()[-1]}))
             continue
-        if top_pjit_eqn(art.jaxpr) is None:
+        if top_jit_eqn(art.jaxpr) is None:
             out.append(Finding(
                 rule="trace/recompile_hazard", severity="warn", target=name,
                 message="entry point is not one top-level jitted program "

@@ -8,7 +8,7 @@ built is itself a finding — see trace/recompile_hazard).
 
 Tracing is the whole story here: nothing in this package compiles or runs
 a step.  ``jax.make_jaxpr`` on a jitted function yields a single top-level
-``pjit`` equation whose params carry ``donated_invars`` — that plus a
+``jit`` equation whose params carry ``donated_invars`` — that plus a
 recursive equation walk is enough for every rule in ``rules_trace``.
 """
 from __future__ import annotations
@@ -17,7 +17,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, Optional, Sequence, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 @dataclass(frozen=True)
@@ -41,7 +41,7 @@ class TraceTarget:
 @dataclass(frozen=True)
 class TraceArtifact:
     target: TraceTarget
-    jaxpr: Optional[Any] = None          # jax.core.ClosedJaxpr
+    jaxpr: Optional[Any] = None          # jax.extend.core.ClosedJaxpr
     out_shape: Optional[Any] = None      # pytree of ShapeDtypeStruct
     error: Optional[str] = None
     meta: Dict[str, Any] = field(default_factory=dict)
@@ -64,7 +64,7 @@ def trace(target: TraceTarget) -> TraceArtifact:
 # --------------------------------------------------------------------------
 
 def _sub_jaxprs(params: Dict[str, Any]) -> Iterator[Any]:
-    """Yield every jaxpr nested in an equation's params (scan/cond/pjit/...)."""
+    """Yield every jaxpr nested in an equation's params (scan/cond/jit/...)."""
     for v in params.values():
         leaves = v if isinstance(v, (tuple, list)) else (v,)
         for leaf in leaves:
@@ -86,17 +86,17 @@ def iter_eqns(jaxpr) -> Iterator[Any]:
             yield from iter_eqns(sub)
 
 
-def top_pjit_eqn(jaxpr):
-    """The sole top-level pjit equation of a traced jitted fn, or None.
+def top_jit_eqn(jaxpr):
+    """The sole top-level jit equation of a traced jitted fn, or None.
 
-    make_jaxpr of ``jax.jit(f)`` produces exactly one pjit eqn wrapping the
-    body; its params hold ``donated_invars`` (leaf-expanded, one bool per
-    flattened input).
+    make_jaxpr of ``jax.jit(f)`` produces exactly one ``jit`` eqn wrapping
+    the body; its params hold ``donated_invars`` (leaf-expanded, one bool
+    per flattened input).
     """
     inner = jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr
-    pjits = [e for e in inner.eqns if e.primitive.name == "pjit"]
-    if len(inner.eqns) == len(pjits) == 1:
-        return pjits[0]
+    jits = [e for e in inner.eqns if e.primitive.name == "jit"]
+    if len(inner.eqns) == len(jits) == 1:
+        return jits[0]
     return None
 
 
@@ -104,7 +104,7 @@ def donated_invars(artifact: TraceArtifact) -> Optional[Tuple[bool, ...]]:
     """Leaf-level donation mask of the target's top-level jit, or None."""
     if artifact.jaxpr is None:
         return None
-    eqn = top_pjit_eqn(artifact.jaxpr)
+    eqn = top_jit_eqn(artifact.jaxpr)
     if eqn is None or "donated_invars" not in eqn.params:
         return None
     return tuple(eqn.params["donated_invars"])

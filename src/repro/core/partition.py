@@ -131,13 +131,26 @@ def join_stage_params(cfg: ModelConfig, plan: PartitionPlan,
     return full
 
 
+def stage_unembed(cfg: ModelConfig, stage_params, x):
+    """The last stage's unembedding of final-normed hidden states x."""
+    if "tied_unembed" in stage_params:
+        # frozen snapshot of the embedding: gradients must not flow into
+        # it (stage 0 owns the trainable copy)
+        up = dict(stage_params)
+        up["tok_embed"] = jax.lax.stop_gradient(up.pop("tied_unembed"))
+        return M.unembed(cfg, up, x)
+    return M.unembed(cfg, stage_params, x)
+
+
 def stage_forward(cfg: ModelConfig, plan: PartitionPlan, k: int, stage_params,
-                  batch_or_x, *, remat=True, shard_x=None):
+                  batch_or_x, *, remat=True, shard_x=None, logits=True):
     """Forward of stage k alone.
 
     Stage 0 consumes the raw batch (dict); later stages consume the boundary
     activation (B, S, d).  Returns (output, aux): output is the boundary
-    activation for interior stages or logits for the last stage.
+    activation for interior stages or logits for the last stage — or, with
+    ``logits=False``, the last stage's final-normed hidden states (unembed
+    them with ``stage_unembed``).
     """
     g0, g1 = plan.bounds[k]
     n = g1 - g0
@@ -158,13 +171,9 @@ def stage_forward(cfg: ModelConfig, plan: PartitionPlan, k: int, stage_params,
     aux["n_prefix"] = n_prefix
     if k == plan.n_stages - 1:
         x = M.norm_apply_final(cfg, stage_params, x)
-        if "tied_unembed" in stage_params:
-            # frozen snapshot of the embedding: gradients must not flow into
-            # it (stage 0 owns the trainable copy)
-            up = dict(stage_params)
-            up["tok_embed"] = jax.lax.stop_gradient(up.pop("tied_unembed"))
-            return M.unembed(cfg, up, x), aux
-        return M.unembed(cfg, stage_params, x), aux
+        if not logits:
+            return x, aux
+        return stage_unembed(cfg, stage_params, x), aux
     if cfg.enc_dec:
         return (x, enc_out), aux
     return x, aux

@@ -30,7 +30,6 @@ from __future__ import annotations
 from typing import List, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 
 from repro.dist import lifecycle
 from repro.dist.placement import PlacementPlan
@@ -228,11 +227,11 @@ class StageExecutor:
         return [jax.device_get(p) for p in self.params]  # repro: allow-host-sync
 
     def finalize(self, trainer, state, phase_name: str = "parallel") -> None:
-        """Hand results back to the TrainState: params re-hosted (so joins,
-        eval, and later phases never mix committed devices), the pending
-        device-resident losses flushed in one transfer, counters folded in."""
-        state.stage_params = [jax.tree_util.tree_map(jnp.asarray, sp)
-                              for sp in self.gather()]
+        """Hand results back to the TrainState: params pulled to the host
+        (so joins, eval, and later phases never mix committed devices), the
+        pending device-resident losses flushed in one transfer, counters
+        folded in."""
+        state.stage_params = self.gather()
         state.cum_macs += self.cum_macs
         self.cum_macs = 0
         if self.be.kind == "mlp":

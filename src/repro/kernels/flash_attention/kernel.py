@@ -23,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.plan import (BlockPlan, KernelPlan, ScalarPrefetchPlan,
                                 ScratchPlan, as_block_spec, as_scratch)
@@ -80,20 +81,23 @@ def decode_plan(b, lc, h, kv, d, *, bk=DEFAULT_BK,
     return KernelPlan(
         family="flash_attention", entry="decode_attention",
         grid=(b, kv, nk),
+        scalar_prefetch=(
+            ScalarPrefetchPlan("pos", (b,), "int32", max_value=2 ** 31 - 1),
+        ),
         inputs=(
-            BlockPlan("pos", (1,), lambda b_, kv_, ik: (b_,), (b,),
-                      "int32", memory_space="smem"),
-            BlockPlan("q", (1, 1, g, d), lambda b_, kv_, ik: (b_, kv_, 0, 0),
+            BlockPlan("q", (1, 1, g, d),
+                      lambda b_, kv_, ik, pos_ref: (b_, kv_, 0, 0),
                       (b, kv, g, d), dtype),
             BlockPlan("k", (1, 1, bk_, d),
-                      lambda b_, kv_, ik: (b_, kv_, ik, 0),
+                      lambda b_, kv_, ik, pos_ref: (b_, kv_, ik, 0),
                       (b, kv, lc_p, d), dtype),
             BlockPlan("v", (1, 1, bk_, d),
-                      lambda b_, kv_, ik: (b_, kv_, ik, 0),
+                      lambda b_, kv_, ik, pos_ref: (b_, kv_, ik, 0),
                       (b, kv, lc_p, d), dtype),
         ),
         outputs=(
-            BlockPlan("o", (1, 1, g, d), lambda b_, kv_, ik: (b_, kv_, 0, 0),
+            BlockPlan("o", (1, 1, g, d),
+                      lambda b_, kv_, ik, pos_ref: (b_, kv_, 0, 0),
                       (b, kv, g, d), dtype),
         ),
         scratch=(
@@ -122,23 +126,24 @@ def paged_decode_plan(b, nb, bs, h, kv, d, *, n_blocks,
         scalar_prefetch=(
             ScalarPrefetchPlan("block_tables", (b, nb), "int32",
                                max_value=n_blocks - 1),
+            ScalarPrefetchPlan("pos", (b,), "int32", max_value=2 ** 31 - 1),
         ),
         inputs=(
-            BlockPlan("pos", (1,), lambda b_, kv_, ik, bt_ref: (b_,), (b,),
-                      "int32", memory_space="smem"),
             BlockPlan("q", (1, 1, g, d),
-                      lambda b_, kv_, ik, bt_ref: (b_, kv_, 0, 0),
+                      lambda b_, kv_, ik, bt_ref, pos_ref: (b_, kv_, 0, 0),
                       (b, kv, g, d), dtype),
             BlockPlan("k", (1, 1, bs, d),
-                      lambda b_, kv_, ik, bt_ref: (bt_ref[b_, ik], kv_, 0, 0),
+                      lambda b_, kv_, ik, bt_ref, pos_ref:
+                      (bt_ref[b_, ik], kv_, 0, 0),
                       (n_blocks, kv, bs, d), dtype),
             BlockPlan("v", (1, 1, bs, d),
-                      lambda b_, kv_, ik, bt_ref: (bt_ref[b_, ik], kv_, 0, 0),
+                      lambda b_, kv_, ik, bt_ref, pos_ref:
+                      (bt_ref[b_, ik], kv_, 0, 0),
                       (n_blocks, kv, bs, d), dtype),
         ),
         outputs=(
             BlockPlan("o", (1, 1, g, d),
-                      lambda b_, kv_, ik, bt_ref: (b_, kv_, 0, 0),
+                      lambda b_, kv_, ik, bt_ref, pos_ref: (b_, kv_, 0, 0),
                       (b, kv, g, d), dtype),
         ),
         scratch=(
@@ -252,7 +257,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     # valid slots: arange(lc) <= pos (ring caches: every written slot is
     # valid once pos >= lc — same contract as ref.decode_attention)
     slot = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = (slot < lc) & (slot <= pos_ref[0])
+    mask = (slot < lc) & (slot <= pos_ref[pl.program_id(0)])
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -279,7 +284,10 @@ def decode_attention_tpu(q, k_cache, v_cache, pos, *, window=0,
     q: (B, 1, H, D); caches: (B, Lc, KV, D); pos: scalar int32 or per-request
     (B,) vector.  `window` only affects the cache LAYOUT (ring), not the
     validity mask, so it is accepted for signature parity with the ref.
-    Returns (B, 1, H, D).
+    `bk` is the cache tile: the online softmax folds the cache in blocks of
+    `bk` slots, so at equal `bk` this kernel and the paged one (whose tile
+    is its block size) visit the same blocks in the same order and agree
+    bitwise.  Returns (B, 1, H, D).
     """
     b, _, h, d = q.shape
     lc, kv = k_cache.shape[1], k_cache.shape[2]
@@ -289,8 +297,8 @@ def decode_attention_tpu(q, k_cache, v_cache, pos, *, window=0,
         interpret = jax.default_backend() != "tpu"
 
     kp = decode_plan(b, lc, h, kv, d, bk=bk, dtype=str(q.dtype))
-    bk_ = kp.inputs[2].block_shape[2]
-    pad = kp.inputs[2].array_shape[2] - lc
+    bk_ = kp.inputs[1].block_shape[2]
+    pad = kp.inputs[1].array_shape[2] - lc
 
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     qt = q[:, 0].reshape(b, kv, g, d)                    # (B, KV, G, D)
@@ -301,13 +309,17 @@ def decode_attention_tpu(q, k_cache, v_cache, pos, *, window=0,
         vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad), (0, 0)))
 
     kernel = functools.partial(_decode_kernel, scale=scale, bk=bk_, lc=lc)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(kp.scalar_prefetch),
         grid=kp.grid,
         in_specs=[as_block_spec(bp) for bp in kp.inputs],
         out_specs=as_block_spec(kp.outputs[0]),
-        out_shape=jax.ShapeDtypeStruct(kp.outputs[0].array_shape, q.dtype),
         scratch_shapes=[as_scratch(sp) for sp in kp.scratch],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(kp.outputs[0].array_shape, q.dtype),
         interpret=interpret,
     )(pos_b, qt, kt, vt)
     return out.reshape(b, 1, h, d)
@@ -338,7 +350,6 @@ def paged_decode_attention_tpu(q, k_pages, v_pages, block_tables, pos, *,
     view never materializes.  `window` only affects cache LAYOUT (ring),
     not the mask — signature parity with the ref.  Returns (B, 1, H, D).
     """
-    from jax.experimental.pallas import tpu as pltpu
     b, _, h, d = q.shape
     n_blocks, bs, kv = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     nb = block_tables.shape[1]

@@ -8,6 +8,10 @@ the serving engine is testable against both.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
+
 from repro.kernels.dispatch import decide
 
 from . import ref
@@ -20,16 +24,45 @@ def flash_attention(q, k, v, *, causal=True, window=0, chunk=512):
     policy); both backends accumulate scores and the softmax in fp32 and
     return the input dtype."""
     if decide("flash_attention", q.shape, q.dtype).use_pallas:
-        from .kernel import flash_attention_tpu
-        return flash_attention_tpu(q, k, v, causal=causal, window=window)
+        return _flash_pallas(q, k, v, causal, window, chunk)
     return ref.chunked_attention(q, k, v, causal=causal, window=window, chunk=chunk)
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, window=0):
-    """Single-token decode over a KV cache (ring-buffered if window>0)."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_pallas(q, k, v, causal, window, chunk):
+    """Pallas forward; the backward is the VJP of ``ref.chunked_attention``.
+
+    There is no Pallas backward kernel yet: the gradient recomputes the
+    forward in chunked jnp (one KV chunk of scores live at a time, the scan
+    body rematerialised) and differentiates that."""
+    from .kernel import flash_attention_tpu
+    return flash_attention_tpu(q, k, v, causal=causal, window=window)
+
+
+def _flash_fwd(q, k, v, causal, window, chunk):
+    return _flash_pallas(q, k, v, causal, window, chunk), (q, k, v)
+
+
+def _flash_bwd(causal, window, chunk, res, g):
+    _, vjp = jax.vjp(functools.partial(ref.chunked_attention, causal=causal,
+                                       window=window, chunk=chunk), *res)
+    return vjp(g)
+
+
+_flash_pallas.defvjp(_flash_fwd, _flash_bwd)
+
+
+def decode_attention(q, k_cache, v_cache, pos, *, window=0, block=None):
+    """Single-token decode over a KV cache (ring-buffered if window>0).
+
+    ``block``: the Pallas kernel's cache tile (its default when None).  The
+    pool that owns the cache chooses it: a serving engine passes its paged
+    block size so its contiguous and paged pools fold the softmax in the
+    same blocks and decode the same tokens."""
     if decide("flash_attention", k_cache.shape, q.dtype).use_pallas:
-        from .kernel import decode_attention_tpu
-        return decode_attention_tpu(q, k_cache, v_cache, pos, window=window)
+        from .kernel import DEFAULT_BK, decode_attention_tpu
+        return decode_attention_tpu(q, k_cache, v_cache, pos, window=window,
+                                    bk=block or DEFAULT_BK)
     return ref.decode_attention(q, k_cache, v_cache, pos, window=window)
 
 

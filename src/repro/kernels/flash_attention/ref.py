@@ -50,7 +50,8 @@ def chunked_attention(q, k, v, *, causal=True, window=0, chunk=512, scale=None):
     """Flash-style attention: scan over KV chunks with running (m, l, acc).
 
     q: (B, Sq, H, D); k, v: (B, Sk, KV, D); Sq == Sk or q aligned to end of k.
-    Score memory is O(Sq * chunk) instead of O(Sq * Sk).
+    Score memory is O(Sq * chunk) instead of O(Sq * Sk), in the forward
+    and in its VJP.
     """
     b, sq, h, d = q.shape
     sk, kvh = k.shape[1], k.shape[2]
@@ -96,8 +97,11 @@ def chunked_attention(q, k, v, *, causal=True, window=0, chunk=512, scale=None):
     acc0 = jnp.zeros((b, kvh, g, sq, d), dtype=jnp.float32)
     kc_t = jnp.moveaxis(kc, 1, 0)
     vc_t = jnp.moveaxis(vc, 1, 0)
+    # the rematerialised body keeps the VJP's residuals to the carries: one
+    # chunk of scores is live at a time in the backward too
     (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, acc0), (kc_t, vc_t, jnp.arange(n_chunks)))
+        jax.checkpoint(body), (m0, l0, acc0),
+        (kc_t, vc_t, jnp.arange(n_chunks)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     out = jnp.moveaxis(out, 3, 1).reshape(b, sq, h, d)
     return out.astype(q.dtype)

@@ -29,13 +29,15 @@ class BlockPlan:
     index_map: Callable[..., Tuple[int, ...]]
     array_shape: Tuple[int, ...]
     dtype: str = "float32"
-    memory_space: str = "vmem"         # "vmem" | "smem"
+    memory_space: str = "vmem"         # "vmem" | "smem" | "any" (HBM, DMA'd
+                                       # by the kernel; block = whole array)
 
 
 @dataclass(frozen=True)
 class ScratchPlan:
     """VMEM scratch buffer; ``accumulator=True`` marks running state that
-    must accumulate in fp32 regardless of the operand compute dtype."""
+    must accumulate in fp32 regardless of the operand compute dtype.
+    ``dtype="dma_semaphore"`` declares DMA semaphores of ``shape``."""
     name: str
     shape: Tuple[int, ...]
     dtype: str = "float32"
@@ -74,6 +76,8 @@ class KernelPlan:
 def as_block_spec(bp: BlockPlan):
     """BlockPlan -> pl.BlockSpec (lazy Pallas import)."""
     from jax.experimental import pallas as pl
+    if bp.memory_space == "any":
+        return pl.BlockSpec(memory_space=pl.ANY)
     if bp.memory_space == "smem":
         from jax.experimental.pallas import tpu as pltpu
         return pl.BlockSpec(bp.block_shape, bp.index_map,
@@ -82,7 +86,9 @@ def as_block_spec(bp: BlockPlan):
 
 
 def as_scratch(sp: ScratchPlan):
-    """ScratchPlan -> pltpu.VMEM scratch shape (lazy Pallas import)."""
+    """ScratchPlan -> pltpu scratch shape (lazy Pallas import)."""
     import jax.numpy as jnp
     from jax.experimental.pallas import tpu as pltpu
+    if sp.dtype == "dma_semaphore":
+        return pltpu.SemaphoreType.DMA(sp.shape)
     return pltpu.VMEM(sp.shape, jnp.dtype(sp.dtype))

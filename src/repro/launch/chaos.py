@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import enable_compile_cache
+
 SCHEMA = "repro.resilience/1"
 
 TINY = {"n_ticks": 3, "n_train": 256, "batch_size": 64, "mixed_seeds": (0,)}
@@ -204,6 +206,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", default="results/RESILIENCE_8.json",
                     help="report path ('' disables)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     print(f"# repro.resilience chaos sweep: preset={args.preset} "
           f"seed={args.seed}")

@@ -56,6 +56,7 @@ from repro.obs.events import EventLog
 from repro.obs.metrics import TTFT_MS_BUCKETS, Histogram
 from repro.obs.registry import SCHEMA, MetricsRegistry
 from repro.obs.trace import Tracer
+from repro.runtime import enable_compile_cache
 from repro.serve import Engine, GenerationConfig, Request
 
 # Workload presets.  Prompt lengths come from a tiny ladder (the engine
@@ -343,6 +344,7 @@ def main(argv=None) -> int:
                          "baseline on the same workload; exit nonzero "
                          "unless paging wins (BENCH_10 mode)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.compare:
         report = run_compare(args.preset, seed=args.seed, n=args.n,

@@ -48,7 +48,8 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
     assert shape[0] * shape[1] == 256, "one pod = 256 chips"
     full = ((2,) + tuple(shape)) if multi_pod else tuple(shape)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(full, axes)
+    return jax.make_mesh(full, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple:

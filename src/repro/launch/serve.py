@@ -7,7 +7,10 @@ requests, and prints throughput.
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --smoke \
       --batch 4 --prompt-len 64 --new-tokens 32 [--window 256] \
-      [--slots 4] [--stages 2] [--temperature 0.8 --top-k 40 --top-p 0.95]
+      [--slots 4] [--stages 2] [--temperature 0.8 --top-k 40 --top-p 0.95] \
+      [--paged]
+
+``main(argv)`` returns the completions.
 """
 from __future__ import annotations
 
@@ -20,6 +23,7 @@ from repro.configs import ARCH_NAMES, get
 from repro.core import partition
 from repro.data.lm import synthetic_token_stream
 from repro.models import model as M
+from repro.runtime import enable_compile_cache
 from repro.serve import Engine, GenerationConfig, Request
 
 
@@ -33,9 +37,10 @@ def build_engine(cfg, args):
                         for k in range(plan.n_stages)]
         return Engine(cfg, plan=plan, stage_params=stage_params,
                       max_slots=args.slots, decode_block=args.decode_block,
-                      precision=precision)
+                      precision=precision, paged=args.paged)
     return Engine(cfg, params, max_slots=args.slots,
-                  decode_block=args.decode_block, precision=precision)
+                  decode_block=args.decode_block, precision=precision,
+                  paged=args.paged)
 
 
 def synthetic_requests(cfg, args) -> list:
@@ -49,7 +54,7 @@ def synthetic_requests(cfg, args) -> list:
             for i in range(args.batch)]
 
 
-def main():
+def main(argv=None) -> list:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b", choices=ARCH_NAMES)
     ap.add_argument("--smoke", action="store_true")
@@ -71,8 +76,11 @@ def main():
                     help="serving precision policy: activations + the slot "
                          "cache pool in the compute dtype, fp32 sampling "
                          "logits (default: the arch config's dtype)")
-    args = ap.parse_args()
+    ap.add_argument("--paged", action="store_true",
+                    help="block-paged KV pool (default: contiguous slots)")
+    args = ap.parse_args(argv)
     args.slots = args.slots or args.batch
+    enable_compile_cache()
 
     cfg = get(args.arch, smoke=args.smoke)
     if args.window:
@@ -92,6 +100,7 @@ def main():
           f"stages={args.stages}, window={cfg.sliding_window or 'full'}"
           f"{cache_note})")
     print("sample:", list(outs[0].tokens[:16]))
+    return outs
 
 
 if __name__ == "__main__":

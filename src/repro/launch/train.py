@@ -1,8 +1,11 @@
 """Production training launcher.
 
-On a real TPU slice this runs the sharded train step over the production
-mesh; on CPU (this container) it falls back to single-device execution with
-the same code path (reduced configs via --smoke).
+On a slice of 256 or more chips the train step runs sharded over the
+production mesh; with fewer devices (one chip, or the CPU with
+``JAX_PLATFORMS=cpu``) the same code path runs unsharded on one device.
+``--smoke`` picks the reduced configs sized for CPU runs; without it the
+published widths run (``chip_smoke.py`` drives qwen2-1.5b that way on one
+v5e chip).  ``main(argv)`` returns the run's ``History``.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --smoke \
@@ -33,10 +36,12 @@ from repro.launch.steps import (build_train_step, pick_accum,
 from repro.configs.base import InputShape
 from repro.models import model as M
 from repro.optim import cosine_warmup, make_optimizer
+from repro.runtime import enable_compile_cache
 from repro.train import StageSpec, TrainSpec, recipes
+from repro.train.history import History
 
 
-def main():
+def main(argv=None) -> History:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b",
                     choices=ARCH_NAMES + ["paper_mlp"])
@@ -79,7 +84,7 @@ def main():
                     help="force N host devices (CPU: sets XLA_FLAGS "
                          "--xla_force_host_platform_device_count pre-init) "
                          "and place stages across them")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.devices:
         # must precede every jax backend touch in this process
@@ -89,6 +94,7 @@ def main():
         raise SystemExit("--dist requires --mode pnn (stage placement only "
                          "exists for partitioned training)")
 
+    enable_compile_cache()
     from repro.plan import parse_stages
     stage_strategy, n_stages = parse_stages(args.stages)
 
@@ -115,7 +121,10 @@ def main():
     def next_batch(_):
         return {k: jnp.asarray(v) for k, v in next(it).items()}
 
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    # built on the device, kept on the host: the trainer puts on the device
+    # only what each phase trains
+    params = jax.device_get(  # repro: allow-host-sync
+        M.init_params(cfg, jax.random.PRNGKey(0)))
     step0 = 0
     if args.resume:
         step0 = latest_step(args.resume) or 0
@@ -195,6 +204,7 @@ def main():
             opt = mixed_precision(opt, loss_scale=prec.loss_scale,
                                   dynamic=prec.dynamic_scale,
                                   growth_interval=prec.scale_growth_interval)
+        hist = History()
         state = opt.init(params)
         shape = InputShape("cli", args.seq, args.batch, "train")
         if use_mesh:
@@ -228,6 +238,8 @@ def main():
         for i in range(args.steps):
             params, state, metrics = step_fn(params, state, next_batch(i))
             if (i + 1) % max(args.steps // 5, 1) == 0 or i == 0:
+                hist.log(phase="baseline", stage=-1, step=step0 + i,
+                         loss=float(metrics["ce"]))
                 print(f"step {step0+i+1:4d} ce={float(metrics['ce']):.3f} "
                       f"grad_norm={float(metrics['grad_norm']):.2f} "
                       f"({(i+1)*args.batch*args.seq/(time.time()-t0):.0f} tok/s)")
@@ -236,6 +248,7 @@ def main():
         path = save_checkpoint(args.ckpt_dir, step0 + args.steps,
                                {"params": params})
         print("saved:", path)
+    return hist
 
 
 def _print_plan(strategy: str, plan) -> None:
@@ -292,6 +305,7 @@ def _run_paper_mlp(args, strategy: str, n_stages: int):
     if args.ckpt_dir:
         path = save_checkpoint(args.ckpt_dir, epochs, {"params": params})
         print("saved:", path)
+    return hist
 
 
 if __name__ == "__main__":

@@ -19,6 +19,7 @@ import argparse
 import sys
 
 from repro.configs import ARCH_NAMES
+from repro.runtime import enable_compile_cache
 from repro.verify import Context, all_oracles, run_oracle, write_report
 from repro.verify.oracle import PRESETS
 
@@ -40,6 +41,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", default="results/CONFORMANCE_5.json",
                     help="conformance report path ('' disables)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     oracles = all_oracles(tags=args.tags.split(",") if args.tags else None)
     if args.only:

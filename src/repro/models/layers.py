@@ -186,7 +186,7 @@ def attention_apply(p, x, cfg, *, rope_cs=None, causal=True, window=0,
 
 
 def attention_decode(p, x, cfg, cache_kv, pos, *, rope_cs=None, window=0,
-                     cross_kv=None, paged=None):
+                     cross_kv=None, paged=None, kv_block=None):
     """One-token decode. x: (B,1,d). cache_kv: (k,v) each (B,Lc,KV,hd) —
     or, when ``paged`` is set, physical block pools (NB,BS,KV,hd).
 
@@ -196,6 +196,8 @@ def attention_decode(p, x, cfg, cache_kv, pos, *, rope_cs=None, window=0,
     int32, logical_len the static logical cache length (the ring modulus
     when window>0; free/pad table entries point at the garbage block, which
     is written but never read thanks to the ``slot < logical_len`` mask).
+    kv_block: the decode kernel's tile over a contiguous cache (see
+    ``ops.decode_attention``); a paged cache's tile is its block size.
     Returns (out, new_cache_kv). For cross attention pass cross_kv
     (precomputed encoder k/v) and cache_kv=None.
     """
@@ -230,7 +232,7 @@ def attention_decode(p, x, cfg, cache_kv, pos, *, rope_cs=None, window=0,
     slot = (pos_b % lc) if window else jnp.minimum(pos_b, lc - 1)
     kc = kc.at[jnp.arange(b), slot].set(k[:, 0].astype(kc.dtype))
     vc = vc.at[jnp.arange(b), slot].set(v[:, 0].astype(vc.dtype))
-    out = decode_attention(q, kc, vc, pos, window=window)
+    out = decode_attention(q, kc, vc, pos, window=window, block=kv_block)
     return dense(p["wo"], out.reshape(*x.shape[:2], -1)), (kc, vc)
 
 

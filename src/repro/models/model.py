@@ -227,12 +227,13 @@ def _apply_slot_full(cfg, sp, kind, is_moe, has_ffn, x, rope_cs, enc_out,
 
 
 def _apply_slot_decode(cfg, sp, kind, is_moe, has_ffn, x, rope_cs, pos,
-                       cache_slot, paged=None):
+                       cache_slot, paged=None, kv_block=None):
     """One-token slot application with cache update.
 
     paged: optional ``(block_tables, logical_len)`` routing the attention
     K/V through a block-paged pool (see ``layers.attention_decode``); all
-    other slot kinds stay slot-resident and ignore it.
+    other slot kinds stay slot-resident and ignore it, as they do
+    ``kv_block``, the decode tile over a contiguous attention cache.
     """
     h = L.norm_apply(sp["norm1"], x)
     window = cfg.sliding_window
@@ -240,7 +241,7 @@ def _apply_slot_decode(cfg, sp, kind, is_moe, has_ffn, x, rope_cs, pos,
     if kind == "attn":
         out, (kc, vc) = L.attention_decode(
             sp["attn"], h, cfg, (cache_slot["k"], cache_slot["v"]), pos,
-            rope_cs=rope_cs, window=window, paged=paged)
+            rope_cs=rope_cs, window=window, paged=paged, kv_block=kv_block)
         new_cache["k"], new_cache["v"] = kc, vc
     elif kind == "mamba":
         out, st = L.mamba_decode(sp["mamba"], h, cfg,
@@ -450,7 +451,8 @@ def decode_embed(cfg, params, token, pos):
     return x, rope_cs
 
 
-def decode_groups(cfg, groups_params, cache, x, rope_cs, pos, paged=None):
+def decode_groups(cfg, groups_params, cache, x, rope_cs, pos, paged=None,
+                  kv_block=None):
     """One decode step over a (sub)stack of layer groups.
 
     groups_params / cache are stacked over the same leading group dim (the
@@ -467,23 +469,25 @@ def decode_groups(cfg, groups_params, cache, x, rope_cs, pos, paged=None):
         for i, (kind, is_moe, has_ffn) in enumerate(slots):
             x, nc = _apply_slot_decode(cfg, pgroup[f"slot_{i}"], kind, is_moe,
                                        has_ffn, x, rope_cs, pos,
-                                       cache_g[f"slot_{i}"], paged=paged)
+                                       cache_g[f"slot_{i}"], paged=paged,
+                                       kv_block=kv_block)
             new_cache_g[f"slot_{i}"] = nc
         return x, new_cache_g
 
     return jax.lax.scan(body, x, (groups_params, cache))
 
 
-def decode_step(cfg, params, cache, token, pos, paged=None):
+def decode_step(cfg, params, cache, token, pos, paged=None, kv_block=None):
     """One decode step. token: (B,) int32; pos: scalar int32 OR per-request
     (B,) int32 vector (ragged batches: each request at its own position).
 
-    paged: optional ``(block_tables, logical_len)`` for block-paged K/V.
+    paged: optional ``(block_tables, logical_len)`` for block-paged K/V;
+    kv_block: optional decode tile over a contiguous K/V cache.
     Returns (logits (B,V), new_cache).
     """
     x, rope_cs = decode_embed(cfg, params, token, pos)
     x, new_cache = decode_groups(cfg, params["groups"], cache, x, rope_cs,
-                                 pos, paged=paged)
+                                 pos, paged=paged, kv_block=kv_block)
     x = L.norm_apply(params["final_norm"], x)
     logits = unembed(cfg, params, x)[:, 0]
     return logits, new_cache

@@ -10,6 +10,10 @@ they shipped.  Both sites now route through here, and
 regardless of backend — callers that only trace (``jax.make_jaxpr`` /
 ``jax.eval_shape``, e.g. ``repro.analysis``) see the real donation masks
 without ever compiling, so no CPU "donation unimplemented" warnings fire.
+
+``enable_compile_cache`` is the one place the persistent XLA compile cache
+is switched on; entry points (``main()`` of the launch CLIs and
+``chip_smoke.py``) call it, nothing does at import.
 """
 from __future__ import annotations
 
@@ -20,6 +24,9 @@ from typing import Tuple
 import jax
 
 _ASSUME_ENV = "REPRO_ASSUME_DONATION"
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def donation_assumed() -> bool:
@@ -60,3 +67,18 @@ def assume_donation():
             os.environ.pop(_ASSUME_ENV, None)
         else:
             os.environ[_ASSUME_ENV] = prev
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache and return its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself and
+    it is left alone); otherwise the cache lives at the fixed path
+    ``<repo>/.jax_cache`` — the path is part of the cache key, so it must
+    not move between runs."""
+    path = os.environ.get(_CACHE_ENV)
+    if path:
+        return path
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -94,7 +94,9 @@ class Engine:
         per-request block tables, ``max_cache_tokens`` becomes an exact
         total-token budget shared by all in-flight requests, and common
         prompt prefixes are prefilled once (shared-prefix reuse).  OFF by
-        default — the contiguous path is byte-identical to before."""
+        default — the contiguous path is byte-identical to before.  The
+        contiguous pool's decode kernel walks its cache in ``block_size``
+        tiles too, so the two pools decode the same tokens."""
         if precision is not None:
             from repro.precision import get_policy
             cfg = get_policy(precision).apply_to_model(cfg)
@@ -200,10 +202,13 @@ class Engine:
     # -- forward fns (plain vs staged) --------------------------------------
 
     def _decode_fn(self, params, cache, tok, pos, paged=None):
+        kv_block = self.block_size
         if self.plan is not None:
             return staged.staged_decode_step(self.cfg, self.plan, params,
-                                             cache, tok, pos, paged=paged)
-        return M.decode_step(self.cfg, params, cache, tok, pos, paged=paged)
+                                             cache, tok, pos, paged=paged,
+                                             kv_block=kv_block)
+        return M.decode_step(self.cfg, params, cache, tok, pos, paged=paged,
+                             kv_block=kv_block)
 
     def _prefill_fn(self, params, batch, cache_len):
         if self.plan is not None:

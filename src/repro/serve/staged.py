@@ -81,7 +81,8 @@ def staged_prefill(cfg, plan, stage_params, batch, cache_len):
     return logits, cache, jnp.int32(s)
 
 
-def staged_decode_step(cfg, plan, stage_params, cache, tok, pos, paged=None):
+def staged_decode_step(cfg, plan, stage_params, cache, tok, pos, paged=None,
+                       kv_block=None):
     """One decode step through the stage chain. Same contract as
     ``model.decode_step`` (pos: scalar or per-request vector; ``paged``
     routes attention K/V through one block table shared by every stage —
@@ -91,7 +92,7 @@ def staged_decode_step(cfg, plan, stage_params, cache, tok, pos, paged=None):
     for k in range(plan.n_stages):
         x, nc = M.decode_groups(cfg, stage_params[k]["groups"],
                                 _stage_cache(plan, k, cache), x, rope_cs, pos,
-                                paged=paged)
+                                paged=paged, kv_block=kv_block)
         new_parts.append(nc)
     new_cache = jax.tree_util.tree_map(
         lambda *xs: jnp.concatenate(xs, axis=0), *new_parts)

@@ -427,8 +427,9 @@ class LMBackend:
     # -- params ------------------------------------------------------------
 
     def split(self, params) -> List[dict]:
-        # copy so donated stage buffers can never alias the caller's tree
-        return [_copy_tree(partition.slice_stage_params(
+        # host copies: donated stage buffers can never alias the caller's
+        # tree, and the phases put on the device only what they train
+        return [jax.device_get(partition.slice_stage_params(  # repro: allow-host-sync
             self.cfg, self.plan, params, k)) for k in range(self.n_stages)]
 
     def join(self, stage_params) -> dict:
@@ -436,9 +437,12 @@ class LMBackend:
 
     def make_sils(self, key, kappa: float) -> list:
         # exact legacy key schedule: split(key, n_stages), sils from keys[:n-1]
+        # host-resident like the stage params: a phase puts its own table
+        # on the device (each is d_model x vocab fp32)
         keys = jax.random.split(key, self.n_stages)
-        return [sil_lib.make_sil(keys[k], self.cfg.d_model,
-                                 self.cfg.vocab_size, kappa)
+        return [jax.device_get(  # repro: allow-host-sync
+                    sil_lib.make_sil(keys[k], self.cfg.d_model,
+                                     self.cfg.vocab_size, kappa))
                 for k in range(self.n_stages - 1)]
 
     def before_stage_train(self, stage_params: list, k: int) -> None:
@@ -460,6 +464,14 @@ class LMBackend:
         if self.cfg.frontend == "vision":
             return x[:, self.cfg.vision_tokens:]
         return x
+
+    def _objective(self, last_params, h, labels, aux, mask):
+        """CE (+ MoE aux) of the last stage's final hidden states ``h``,
+        chunked over tokens so the whole logits never exist at once."""
+        return losses.chunked_train_objective(
+            self.cfg, functools.partial(partition.stage_unembed, self.cfg,
+                                        last_params),
+            self._trim_vision(h), labels, aux, mask)
 
     def _jit_step(self, step):
         return jax.jit(step, donate_argnums=donate_argnums(0, 1))
@@ -490,23 +502,36 @@ class LMBackend:
         differentiated tree — zero grad/optimizer-state cost.  Gradients of
         ``accum`` microbatches accumulate in fp32 inside the jitted step;
         the loss is scaled by the live loss scale (1.0 unless the optimizer
-        is a mixed_precision fp16 wrapper)."""
+        is a mixed_precision fp16 wrapper).
+
+        Returns ``step(sp, st, xin, labels, mask=None)``; the SIL table is
+        passed to the jitted program as a trailing argument (a closed-over
+        array would be embedded in the program as a constant)."""
+        jitted = self._jit_step(self._stage_step(k, opt, stage_params_struct,
+                                                 accum))
+
+        def call(sp, st, xin, labels, mask=None):
+            return jitted(sp, st, xin, labels, mask, sil)
+        return call
+
+    def _stage_step(self, k: int, opt, stage_params_struct, accum: int):
+        """The un-jitted stage-k step ``(sp, st, xin, labels, mask, sil)``."""
         cfg, plan = self.cfg, self.plan
         last = k == self.n_stages - 1
         pspecs = self._grad_pspecs(self.trainable(stage_params_struct)) \
             if stage_params_struct is not None else None
 
-        def step(sp, st, xin, labels, mask=None):
+        def step(sp, st, xin, labels, mask, sil):
             train, frozen = self._split_frozen(sp)
             scale = precision_lib.read_loss_scale(st)
 
             def loss_fn(p, xin, labels, mask):
-                out, aux = partition.stage_forward(cfg, plan, k,
-                                                   {**p, **frozen}, xin,
-                                                   shard_x=self.shard_x)
+                full = {**p, **frozen}
+                out, aux = partition.stage_forward(cfg, plan, k, full, xin,
+                                                   shard_x=self.shard_x,
+                                                   logits=False)
                 if last:
-                    loss, _ = losses.train_objective(
-                        cfg, self._trim_vision(out), labels, aux, mask)
+                    loss, _ = self._objective(full, out, labels, aux, mask)
                     return loss * scale
                 bound = out[0] if cfg.enc_dec else out
                 bound = self._trim_vision(bound)
@@ -524,7 +549,7 @@ class LMBackend:
             new_train, st2 = opt.update(grads, st, train)
             return {**new_train, **frozen}, st2, loss / scale
 
-        return self._jit_step(step)
+        return step
 
     def build_parallel_stage_step(self, k: int, opt, sil_in, sil_target,
                                   stage_params_struct=None, accum: int = 1):
@@ -536,28 +561,37 @@ class LMBackend:
         ``sil_in`` is expected to be pre-pinned to the stage's device.
 
         ``sil_target`` is SIL_k (None for the last stage, which trains CE);
-        math is identical to ``synthetic_input`` + ``build_stage_step``."""
+        math is identical to ``synthetic_input`` + ``build_stage_step``.
+        Both tables are trailing arguments of the jitted program."""
         if k == 0:
             raise ValueError("stage 0 consumes the real batch; use "
                              "build_stage_step")
-        inner = self.build_stage_step(k, opt, sil_target,
-                                      stage_params_struct, accum=accum)
+        inner = self._stage_step(k, opt, stage_params_struct, accum)
         act = self.cfg.activation_dtype()
         enc_dec = self.cfg.enc_dec
 
-        def step(sp, st, labels):
+        def step(sp, st, labels, sil_in, sil_target):
             syn = sil_lib.sil_lookup(sil_in, labels).astype(act)
             xin = (syn, None) if enc_dec else syn
-            return inner(sp, st, xin, labels)
+            return inner(sp, st, xin, labels, None, sil_target)
 
-        return self._jit_step(step)
+        jitted = self._jit_step(step)
+
+        def call(sp, st, labels):
+            return jitted(sp, st, labels, sil_in, sil_target)
+        return call
 
     def build_recovery_step(self, j: int, frozen_stages: list, opt,
                             accum: int = 1):
-        """End-to-end CE training of stage j, all other stages frozen."""
-        cfg, plan = self.cfg, self.plan
+        """End-to-end CE training of stage j, all other stages frozen.
 
-        def step(pj, st, batch):
+        The frozen stages are trailing arguments of the jitted program
+        (closed over, they would be embedded in it as constants)."""
+        cfg, plan = self.cfg, self.plan
+        others = tuple(None if k == j else p
+                       for k, p in enumerate(frozen_stages))
+
+        def step(pj, st, batch, frozen_stages):
             train, snap = self._split_frozen(pj)
             scale = precision_lib.read_loss_scale(st)
 
@@ -568,17 +602,21 @@ class LMBackend:
                     p = {**pj_, **snap} if k == j \
                         else jax.lax.stop_gradient(frozen_stages[k])
                     x, aux = partition.stage_forward(cfg, plan, k, p, x,
-                                                     shard_x=self.shard_x)
-                loss, _ = losses.train_objective(
-                    cfg, self._trim_vision(x), batch["labels"], aux,
-                    batch.get("mask"))
+                                                     shard_x=self.shard_x,
+                                                     logits=False)
+                loss, _ = self._objective(p, x, batch["labels"], aux,
+                                          batch.get("mask"))
                 return loss * scale
             loss, grads = value_and_accum_grads(loss_fn, train, (batch,),
                                                 accum)
             new_train, st2 = opt.update(grads, st, train)
             return {**new_train, **snap}, st2, loss / scale
 
-        return self._jit_step(step)
+        jitted = self._jit_step(step)
+
+        def call(pj, st, batch):
+            return jitted(pj, st, batch, others)
+        return call
 
     def build_baseline_step(self, opt, accum: int = 1):
         """Conventional end-to-end training of the UNPARTITIONED network

@@ -25,6 +25,11 @@ class History:
     def __init__(self):
         self.records: List[Record] = []
         self.meta: Dict[str, Any] = {}
+        # one dict per executed phase: name, stage, host-clock start/end
+        # (perf_counter seconds) and, per local device, the process's peak
+        # bytes in use before and after it and the bytes live at its end
+        # (None where the backend keeps no memory stats)
+        self.phases: List[Dict[str, Any]] = []
 
     def log(self, **kw) -> None:
         self.records.append(Record(**kw))

@@ -54,11 +54,14 @@ def _resolve_placement(plan, devices, trainer, state):
                      stage_bytes=stage_bytes)
 
 
-def _rehost(tree):
-    """Pull a (possibly device-committed) tree back to uncommitted default-
-    device arrays so later phases can freely mix it with other stages."""
-    return jax.tree_util.tree_map(
-        jnp.asarray, jax.device_get(tree))  # repro: allow-host-sync
+def _to_host(tree):
+    """Stage params live on the host between phases: a phase puts on the
+    device (its placement's, or the default) only the stages and the SIL
+    table it reads, so a stage-sequential run needs one stage's training
+    state at a time, not the whole model's (qwen2-1.5b does not fit a
+    16 GB chip otherwise), and no stage stays committed to the device a
+    placed phase trained it on."""
+    return jax.device_get(tree)  # repro: allow-host-sync
 
 
 @dataclass
@@ -161,20 +164,22 @@ class SilStagePhase(PhaseBase):
                 macs_per_sample=be.stage_macs(k), seed_base=self.seed_base,
                 log_mode="cadence")
         else:
-            step = be.build_stage_step(k, opt, sil, state.stage_params[k],
+            sp = jax.device_put(state.stage_params[k])
+            step = be.build_stage_step(k, opt, jax.device_put(sil), sp,
                                        accum=hp.accum)
-            opt_state = opt.init(be.trainable(state.stage_params[k]))
+            opt_state = opt.init(be.trainable(sp))
             prefix = be.prefix_forward(k) if k else None
-            frozen = tuple(state.stage_params[:k])
+            frozen = jax.device_put(tuple(state.stage_params[:k]))
 
             def inputs(i):
                 batch = be.batch_fn(i)
                 xin = batch if k == 0 else prefix(frozen, batch)
                 return (xin, batch["labels"], batch.get("mask"))
-            state.stage_params[k], _ = trainer.drive_steps(
+            sp, _ = trainer.drive_steps(
                 state, step=step, inputs_fn=inputs, n_steps=hp.steps,
                 phase_name=self.name, stage=k,
-                train_params=state.stage_params[k], opt_state=opt_state)
+                train_params=sp, opt_state=opt_state)
+            state.stage_params[k] = _to_host(sp)
 
 
 # ==========================================================================
@@ -210,15 +215,16 @@ class BoundaryMaterializePhase(PhaseBase):
     def run(self, trainer, state) -> None:
         be = trainer.backend
         fwd = be.prefix_forward(self.upto)
-        frozen = tuple(state.stage_params[: self.upto])
+        producer = None
         if self.plan is not None:
             # producer placement: the prefix forward runs on the device
             # owning the last frozen stage (batches follow the committed
             # params; the cache append pulls to host as before)
             placement = _resolve_placement(self.plan, self.devices,
                                            trainer, state)
-            frozen = jax.device_put(frozen,
-                                    placement.device_for(self.upto - 1))
+            producer = placement.device_for(self.upto - 1)
+        frozen = jax.device_put(tuple(state.stage_params[: self.upto]),
+                                producer)
         old = state.boundary.get("h")
         if old is not None and hasattr(old, "close"):
             old.close()   # re-materialization must not leak a spill file
@@ -305,9 +311,7 @@ class FrozenPrefixPhase(PhaseBase):
                                            trainer, state)
             consumer = placement.device_for(k)
             producer = placement.device_for(k - 1) if k > 0 else consumer
-        train_params = state.stage_params[k]
-        if consumer is not None:
-            train_params = jax.device_put(train_params, consumer)
+        train_params = jax.device_put(state.stage_params[k], consumer)
         if be.kind == "mlp":
             if self.source != "cache" or "h" not in state.boundary:
                 raise ValueError("MLP FrozenPrefixPhase needs a preceding "
@@ -329,7 +333,7 @@ class FrozenPrefixPhase(PhaseBase):
                 seed_base=self.seed_base, log_mode="cadence+last",
                 batch_arrays=batch_arrays)
         else:
-            sil = None if last else state.sils[k]
+            sil = None if last else jax.device_put(state.sils[k], consumer)
             step = be.build_stage_step(k, opt, sil, train_params,
                                        accum=hp.accum)
             opt_state = opt.init(be.trainable(train_params))
@@ -350,9 +354,8 @@ class FrozenPrefixPhase(PhaseBase):
                             jnp.asarray(labels[j:j + b]), m)
             else:
                 prefix = be.prefix_forward(k)
-                frozen = tuple(state.stage_params[:k])
-                if producer is not None:
-                    frozen = jax.device_put(frozen, producer)
+                frozen = jax.device_put(tuple(state.stage_params[:k]),
+                                        producer)
 
                 def inputs(i):
                     batch = be.batch_fn(i)
@@ -366,8 +369,7 @@ class FrozenPrefixPhase(PhaseBase):
                 state, step=step, inputs_fn=inputs, n_steps=hp.steps,
                 phase_name=self.name, stage=k,
                 train_params=train_params, opt_state=opt_state)
-        state.stage_params[k] = _rehost(train_params) \
-            if consumer is not None else train_params
+        state.stage_params[k] = _to_host(train_params)
 
 
 # ==========================================================================
@@ -400,15 +402,18 @@ class RecoveryPhase(PhaseBase):
                 stage=j, macs_per_sample=be.full_macs(),
                 seed_base=self.seed_base, log_mode="every")
         else:
+            frozen = jax.device_put(frozen)
             step = be.build_recovery_step(j, frozen, opt, accum=hp.accum)
-            opt_state = opt.init(be.trainable(state.stage_params[j]))
+            opt_state = opt.init(be.trainable(frozen[j]))
 
             def inputs(i):
                 return (be.batch_fn(i),)
-            state.stage_params[j], _ = trainer.drive_steps(
+            pj, _ = trainer.drive_steps(
                 state, step=step, inputs_fn=inputs, n_steps=n,
                 phase_name=self.name, stage=-1,   # legacy: recovery logs -1
-                train_params=state.stage_params[j], opt_state=opt_state)
+                train_params=frozen.pop(j), opt_state=opt_state)
+            del frozen, step
+            state.stage_params[j] = _to_host(pj)
 
 
 # ==========================================================================
@@ -509,12 +514,13 @@ class ParallelSilPhase(PhaseBase):
         hps = [self.resolve(trainer.spec.stage(k))
                for k in range(be.n_stages)]
         opts = [make_optimizer_for(hp, trainer.spec) for hp in hps]
-        opt_states = [opts[k].init(be.trainable(state.stage_params[k]))
+        params = jax.device_put(list(state.stage_params))
+        sils = jax.device_put(list(state.sils))
+        opt_states = [opts[k].init(be.trainable(params[k]))
                       for k in range(be.n_stages)]
         steps = [be.build_stage_step(
-            k, opts[k],
-            None if k == be.n_stages - 1 else state.sils[k],
-            state.stage_params[k], accum=hps[k].accum)
+            k, opts[k], None if k == be.n_stages - 1 else sils[k],
+            params[k], accum=hps[k].accum)
             for k in range(be.n_stages)]
         pending, logged_steps, logged_stages = [], [], []
         n_steps = max(hp.steps for hp in hps)
@@ -524,13 +530,14 @@ class ParallelSilPhase(PhaseBase):
             for k in range(be.n_stages):
                 if i >= hps[k].steps:
                     continue
-                xin = batch if k == 0 else be.synthetic_input(k, state.sils,
+                xin = batch if k == 0 else be.synthetic_input(k, sils,
                                                               labels)
-                state.stage_params[k], opt_states[k], loss = steps[k](
-                    state.stage_params[k], opt_states[k], xin, labels)
+                params[k], opt_states[k], loss = steps[k](
+                    params[k], opt_states[k], xin, labels)
                 pending.append(loss)
                 logged_steps.append(i)
                 logged_stages.append(k)
             state.step_idx += 1
         trainer.flush_losses(state, pending, logged_steps, self.name,
                              logged_stages)
+        state.stage_params = _to_host(params)

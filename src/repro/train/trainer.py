@@ -17,6 +17,7 @@ scalars are collected and fetched in a single transfer when the phase ends.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -98,9 +99,18 @@ class Trainer:
                 help="samples tail-dropped per epoch by batching").set(
                     self.backend.dropped_per_epoch)
         for phase in phases:
+            peak0 = _device_stat("peak_bytes_in_use")
+            t0 = time.perf_counter()
             with self.tracer.span(type(phase).__name__, cat="phase",
                                   tid=TID_LOOP):
                 phase.run(self, state)
+            state.history.phases.append({
+                "phase": type(phase).__name__,
+                "stage": getattr(phase, "stage", None),
+                "start": t0, "end": time.perf_counter(),
+                "peak_bytes_before": peak0,
+                "peak_bytes": _device_stat("peak_bytes_in_use"),
+                "bytes_in_use": _device_stat("bytes_in_use")})
         for cache in state.boundary.values():
             if hasattr(cache, "close"):
                 cache.close()
@@ -238,3 +248,12 @@ class Trainer:
         # this is already a sanctioned sync point — drain the device-resident
         # metrics accumulated since the last flush (idempotent)
         self.metrics.drain()
+
+
+def _device_stat(key: str) -> list:
+    """Each local device's ``memory_stats()[key]`` (None without stats)."""
+    out = []
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        out.append(stats.get(key) if stats else None)
+    return out

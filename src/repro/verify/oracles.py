@@ -58,6 +58,35 @@ def _flash_attention(ctx: Context):
     return ref_out, opt_out
 
 
+@register("kernel/flash_attention_grad",
+          "Gradients through the Pallas flash attention (custom VJP whose "
+          "backward is the chunked reference's VJP) == gradients of the "
+          "naive reference",
+          Allclose(), tags=("kernel", "train"))
+def _flash_attention_grad(ctx: Context):
+    from repro.kernels.flash_attention import ref
+    from repro.kernels.flash_attention.ops import _flash_pallas
+    shapes = [(1, 128, 4, 2, 64, True, 0), (1, 96, 4, 4, 64, True, 32)]
+    if ctx.preset == "full":
+        shapes.append((2, 256, 8, 2, 128, False, 0))
+    ref_out, opt_out = {}, {}
+    for b, s, h, kv, d, causal, window in shapes:
+        ks = jax.random.split(KEY, 4)
+        q = jax.random.normal(ks[0], (b, s, h, d), jnp.float32)
+        k = jax.random.normal(ks[1], (b, s, kv, d), jnp.float32)
+        v = jax.random.normal(ks[2], (b, s, kv, d), jnp.float32)
+        w = jax.random.normal(ks[3], (b, s, h, d), jnp.float32)
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v) * w).sum()
+        name = f"s{s}_c{int(causal)}_w{window}"
+        opt_out[name] = jax.grad(loss(lambda q, k, v: _flash_pallas(
+            q, k, v, causal, window, 64)), (0, 1, 2))(q, k, v)
+        ref_out[name] = jax.grad(loss(lambda q, k, v: ref.naive_attention(
+            q, k, v, causal=causal, window=window)), (0, 1, 2))(q, k, v)
+    return ref_out, opt_out
+
+
 @register("kernel/decode_attention",
           "Pallas decode attention over a KV cache == reference "
           "(scalar / ragged / ring-full position variants)",

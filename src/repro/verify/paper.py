@@ -56,9 +56,14 @@ PRESETS = {
     # learned model from chance (floor) before the gap is judged.  Budgets
     # are calibrated against the synthetic EMNIST stand-in, where the
     # conventional baseline saturates (~0.99) — harsher on PNN than the
-    # paper's real-EMNIST setting (~0.85 both sides)
+    # paper's real-EMNIST setting (~0.85 both sides).  A quarter of the
+    # data, so N_L is 4x the paper's 5 epochs: the left partition keeps the
+    # paper's 400 optimizer steps against its SIL.  With 100 steps it ends
+    # far from the SIL targets (boundary MSE above the targets' variance,
+    # a fifth of the boundary ReLUs dead), and the right partition's
+    # accuracy then swings with the seed from 0.55 to 0.98
     "tiny": PaperPreset(n_train=28200, n_test=2820, noise=0.5,
-                        n_left=5, n_right=80, n_baseline=40, n_recovery=20,
+                        n_left=20, n_right=80, n_baseline=40, n_recovery=20,
                         lr_recovery=3e-4, budget=0.05, floor=0.60),
     # the paper's own schedule (§3: EMNIST-balanced sizes, §4-§5 epochs);
     # measured gap at this fidelity is ~0.001 (PNN slightly ahead), so the

@@ -54,7 +54,7 @@ def test_host_transfer_fires_on_debug_print_in_scan():
                                  args=(jnp.ones(4),))])
     fs = _findings("trace/host_transfer", ctx)
     assert [f.severity for f in fs] == ["fail"]
-    assert "debug_callback" in fs[0].message
+    assert "debug_print" in fs[0].message
 
 
 def test_dtype_policy_fires_on_mixed_dot():
@@ -162,7 +162,7 @@ def test_recompile_hazard_fires_on_untraceable_entry():
 
 def test_recompile_hazard_warns_on_unjitted_entry():
     def f(x):
-        return x * 2 + 1         # two top-level eqns, no pjit wrapper
+        return x * 2 + 1         # two top-level eqns, no jit wrapper
 
     ctx = _ctx_with([TraceTarget(name="bad/unjitted", fn=f,
                                  args=(jnp.ones(4),))])

@@ -250,6 +250,48 @@ def test_paged_decode_kernel_matches_ref():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("bs", [4, 8])
+def test_contiguous_decode_kernel_at_block_tile_matches_paged_bitwise(bs):
+    """The contiguous decode kernel tiled at the paged block size folds the
+    softmax over the same blocks in the same order as the paged kernel:
+    the outputs agree bit for bit, so greedy tokens cannot depend on the
+    pool."""
+    from repro.kernels.flash_attention import kernel as K
+    rng = np.random.default_rng(1)
+    b, h, kv, d, nb = 3, 4, 2, 8, 5
+    lc = nb * bs - 1
+    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
+    kc = jnp.asarray(rng.normal(size=(b, nb * bs, kv, d)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(b, nb * bs, kv, d)), jnp.float32)
+    bt = jnp.asarray(1 + rng.permutation(b * nb).reshape(b, nb), jnp.int32)
+    pages = [jnp.zeros((b * nb + 1, bs, kv, d), jnp.float32).at[bt].set(
+        c.reshape(b, nb, bs, kv, d)) for c in (kc, vc)]
+    pos = jnp.asarray([2, bs + 1, lc - 1], jnp.int32)
+    ctg = K.decode_attention_tpu(q, kc[:, :lc], vc[:, :lc], pos, bk=bs,
+                                 interpret=True)
+    pgd = K.paged_decode_attention_tpu(q, *pages, bt, pos, logical_len=lc,
+                                       interpret=True)
+    np.testing.assert_array_equal(np.asarray(ctg), np.asarray(pgd))
+
+
+@pytest.mark.parametrize("bs", [4, 8])
+def test_paged_equals_contiguous_pallas_path(serve_world, monkeypatch, bs):
+    """Through the Pallas decode kernels (interpret mode), the contiguous
+    engine tiles its decode at its ``block_size`` and so decodes the same
+    tokens as the paged engine at that block size."""
+    from repro.kernels import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    dispatch.cache_clear()
+    cfg, params = serve_world()
+    reqs = serve_requests(cfg, lens=(8, 5, 7), news=(6, 3, 5))
+    ctg = Engine(cfg, params, max_slots=3, decode_block=4,
+                 block_size=bs).generate(reqs)
+    pgd = Engine(cfg, params, max_slots=3, decode_block=4, paged=True,
+                 block_size=bs).generate(reqs)
+    dispatch.cache_clear()
+    assert [c.tokens for c in pgd] == [c.tokens for c in ctg]
+
+
 # -- scheduler bugfix sweep --------------------------------------------------
 
 def test_take_head_of_line_blocking_fixed():

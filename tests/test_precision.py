@@ -254,11 +254,40 @@ def test_sil_mse_bf16_vs_fp32_ref(monkeypatch):
     assert got == pytest.approx(exp, rel=2e-2)
     loss_k, grad_k = sil_mse_fwd_tpu(act.astype(jnp.bfloat16), sil, lab)
     assert float(loss_k) == pytest.approx(exp, rel=2e-2)
-    assert grad_k.dtype == jnp.bfloat16          # grad in activation dtype
+    assert grad_k.dtype == jnp.float32           # unscaled grad kept fp32
     # the loss gradient wrt bf16 activations flows (custom VJP path)
     g = jax.grad(lambda a: sil_mse(a, sil, lab))(act.astype(jnp.bfloat16))
     assert g.dtype == jnp.bfloat16 and bool(jnp.isfinite(
         g.astype(jnp.float32)).all())
+
+
+@pytest.mark.parametrize("path", ["ref", "pallas"])
+def test_sil_mse_fp16_loss_scaled_grad_vs_fp32(monkeypatch, path):
+    """fp16 activations under the fp16 preset's loss scale: the SIL-MSE
+    gradient reaches fp16 only after the scale lifts it out of fp16's
+    subnormal range, so it matches the fp32 gradient to fp16 rounding.
+    The activations sit near their targets, as in a converging stage,
+    where the unscaled 2/(T*d)*diff is a few fp16 subnormal steps."""
+    from repro.kernels import dispatch
+    from repro.kernels.sil_mse import sil_mse
+    from repro.kernels.sil_mse import ref as sm_ref
+    if path == "pallas":        # kernel in interpret mode on CPU
+        monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    dispatch.cache_clear()
+    scale = P.get_policy("fp16").loss_scale
+    t, d, m = 1024, 512, 47
+    ks = jax.random.split(KEY, 3)
+    sil = jax.random.uniform(ks[0], (d, m)) * 5
+    lab = jax.random.randint(ks[1], (t,), 0, m)
+    act = (sil[:, lab].T + 0.05 * jax.random.normal(ks[2], (t, d))
+           ).astype(jnp.float16)
+    g = jax.grad(lambda a: sil_mse(a, sil, lab) * scale)(act)
+    dispatch.cache_clear()
+    assert g.dtype == jnp.float16
+    exp = np.asarray(sm_ref.sil_mse_grad_act(act, sil, lab) * scale)
+    got = np.asarray(g, np.float32)
+    rel = np.linalg.norm(got - exp) / np.linalg.norm(exp)
+    assert rel < 2e-3, rel
 
 
 # ==========================================================================

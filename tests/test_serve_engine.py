@@ -213,7 +213,8 @@ def test_policy_plumbing_single_device(serve_world):
     from repro.launch.sharding import Policy
     cfg, params = serve_world()
     reqs = serve_requests(cfg, lens=(8,), news=(4,))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     plain = Engine(cfg, params, max_slots=1, decode_block=4).generate(reqs)
     sharded = Engine(cfg, params, max_slots=1, decode_block=4,
                      policy=Policy(cfg, mesh)).generate(reqs)

@@ -308,3 +308,88 @@ def test_tied_sequential_training_still_learns():
     # stage 0 trained the embedding; the joined model keeps that copy
     assert float(jnp.abs(joined["tok_embed"] -
                          params["tok_embed"]).max()) > 0.0
+
+
+# ==========================================================================
+# the chunked last-stage objective and per-phase records
+# ==========================================================================
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_chunked_objective_matches_whole_logits(masked):
+    """Chunking the unembedding + CE over tokens (padding the last chunk)
+    gives the objective of the whole logits."""
+    cfg = get("qwen2-1.5b", smoke=True)
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    x = jax.random.normal(ks[0], (3, 50, cfg.d_model), jnp.float32)
+    w = jax.random.normal(ks[1], (cfg.d_model, cfg.vocab_size)) * 0.05
+    labels = jax.random.randint(ks[2], (3, 50), 0, cfg.vocab_size)
+    mask = (jax.random.uniform(ks[3], (3, 50)) > 0.3) if masked else None
+    aux = {}
+    whole, _ = losses.train_objective(cfg, x @ w, labels, aux, mask)
+    chunked, _ = losses.chunked_train_objective(cfg, lambda h: h @ w, x,
+                                                labels, aux, mask, chunk=32)
+    np.testing.assert_allclose(float(chunked), float(whole), rtol=1e-5)
+    g_whole = jax.grad(lambda x: losses.train_objective(
+        cfg, x @ w, labels, aux, mask)[0])(x)
+    g_chunk = jax.grad(lambda x: losses.chunked_train_objective(
+        cfg, lambda h: h @ w, x, labels, aux, mask, chunk=32)[0])(x)
+    np.testing.assert_allclose(np.asarray(g_chunk), np.asarray(g_whole),
+                               rtol=1e-4, atol=1e-7)
+
+
+def test_lm_stages_train_through_the_chunked_objective(monkeypatch):
+    """A 4k-token step on the chip scans the CE in chunks; a 24-token chunk
+    makes this CPU-sized stage-sequential run (64 tokens a step: two whole
+    chunks and a padded one) take the same scan in the last stage and in
+    recovery, and it gives the losses of the whole-logits path."""
+    from repro.train.phases import (FrozenPrefixPhase, RecoveryPhase,
+                                    SilStagePhase)
+    from repro.train.trainer import Trainer
+    from repro.train.backends import LMBackend
+    from repro.verify import scenarios
+    cfg, plan, batch_fn, spec, params = scenarios.tiny_lm(
+        steps=3, n_stages=2, optimizer="sgdm", lr=0.01)
+
+    def run():
+        be = LMBackend(cfg, plan, batch_fn, spec)
+        _, hist = Trainer(be, spec).run(
+            [SilStagePhase(stage=0),
+             FrozenPrefixPhase(stage=1, source="live"),
+             RecoveryPhase(stage=0, steps=2)],
+            params=params, key=jax.random.PRNGKey(1))
+        return hist.column("loss")
+    whole = run()
+    monkeypatch.setattr(losses, "LOSS_CHUNK", 24)
+    chunked = run()
+    assert len(chunked) == len(whole) == 8
+    assert all(np.isfinite(chunked))
+    np.testing.assert_allclose(chunked, whole, rtol=1e-4)
+
+
+def test_lm_sequential_records_phases_and_keeps_stages_on_host():
+    """Each phase leaves a timing record, and between phases the LM stage
+    params live on the host (numpy), not on the device."""
+    from repro.train.phases import RecoveryPhase, SilStagePhase
+    from repro.train.trainer import Trainer
+    from repro.train.backends import LMBackend
+    from repro.verify import scenarios
+    cfg, plan, batch_fn, spec, params = scenarios.tiny_lm(steps=2,
+                                                          n_stages=2)
+    be = LMBackend(cfg, plan, batch_fn, spec)
+    seen = []
+
+    class Probe(SilStagePhase):
+        def run(self, trainer, state):
+            seen.append([type(leaf) for sp in state.stage_params
+                         for leaf in jax.tree_util.tree_leaves(sp)])
+            super().run(trainer, state)
+
+    _, hist = Trainer(be, spec).run(
+        [Probe(stage=0), RecoveryPhase(stage=0, steps=1), Probe(stage=0)],
+        params=params, key=jax.random.PRNGKey(1))
+    assert len(seen) == 2
+    assert all(t is np.ndarray for types in seen for t in types)
+    assert [(p["phase"], p["stage"]) for p in hist.phases] == [
+        ("Probe", 0), ("RecoveryPhase", 0), ("Probe", 0)]
+    assert all(p["end"] >= p["start"] for p in hist.phases)
+    assert all(np.isfinite(hist.column("loss")))
