@@ -50,10 +50,11 @@ def sil_stage_loss(boundary_act, sil, labels):
     boundary_act: (..., d); labels: int (...) matching leading dims.
     Tokens are flattened; goes through the fused kernel path.
     """
-    d = boundary_act.shape[-1]
-    act = boundary_act.reshape(-1, d)
-    lab = labels.reshape(-1)
-    return sil_mse(act, sil, lab)
+    with jax.named_scope("sil_mse"):
+        d = boundary_act.shape[-1]
+        act = boundary_act.reshape(-1, d)
+        lab = labels.reshape(-1)
+        return sil_mse(act, sil, lab)
 
 
 def train_objective(cfg, logits, labels, aux, mask=None):

@@ -23,9 +23,11 @@ def flash_attention(q, k, v, *, causal=True, window=0, chunk=512):
     Inputs may be any float dtype (bf16/fp16 under a reduced-precision
     policy); both backends accumulate scores and the softmax in fp32 and
     return the input dtype."""
-    if decide("flash_attention", q.shape, q.dtype).use_pallas:
-        return _flash_pallas(q, k, v, causal, window, chunk)
-    return ref.chunked_attention(q, k, v, causal=causal, window=window, chunk=chunk)
+    with jax.named_scope("flash"):
+        if decide("flash_attention", q.shape, q.dtype).use_pallas:
+            return _flash_pallas(q, k, v, causal, window, chunk)
+        return ref.chunked_attention(q, k, v, causal=causal, window=window,
+                                     chunk=chunk)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -44,9 +46,11 @@ def _flash_fwd(q, k, v, causal, window, chunk):
 
 
 def _flash_bwd(causal, window, chunk, res, g):
-    _, vjp = jax.vjp(functools.partial(ref.chunked_attention, causal=causal,
-                                       window=window, chunk=chunk), *res)
-    return vjp(g)
+    with jax.named_scope("flash"):
+        _, vjp = jax.vjp(functools.partial(ref.chunked_attention,
+                                           causal=causal, window=window,
+                                           chunk=chunk), *res)
+        return vjp(g)
 
 
 _flash_pallas.defvjp(_flash_fwd, _flash_bwd)

@@ -12,6 +12,7 @@ the last stage owns the final norm + unembedding.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -136,7 +137,8 @@ def sinusoidal(seq, d):
 
 
 def embed_tokens(cfg, params, tokens, dtype):
-    return params["tok_embed"].astype(dtype)[tokens]
+    with jax.named_scope("embed"):
+        return params["tok_embed"].astype(dtype)[tokens]
 
 
 def encode_audio(cfg, params, frames):
@@ -186,43 +188,51 @@ def _apply_slot_full(cfg, sp, kind, is_moe, has_ffn, x, rope_cs, enc_out,
     """Full-sequence slot application. Returns (x, aux, cache_slot or None)."""
     aux = {"lb_loss": 0.0, "z_loss": 0.0}
     cache = {}
-    h = L.norm_apply(sp["norm1"], x)
-    window = cfg.sliding_window
-    if kind == "attn":
-        out, (k, v) = L.attention_apply(sp["attn"], h, cfg, rope_cs=rope_cs,
-                                        causal=True, window=window)
-        if collect_cache:
-            cache["k"], cache["v"] = k, v
-    elif kind == "mamba":
-        out, st = L.mamba_apply(sp["mamba"], h, cfg)
-        if collect_cache:
-            cache["conv"], cache["ssm"] = st
-    elif kind == "mlstm":
-        out, st = L.mlstm_apply(sp["mlstm"], h, cfg)
-        if collect_cache:
-            cache["C"], cache["n"] = st
-    elif kind == "slstm":
-        out, st = L.slstm_apply(sp["slstm"], h, cfg)
-        if collect_cache:
-            cache["h"], cache["c"], cache["sn"], cache["m"] = st
-    x = L.residual_add(x, out)
+    # the attention and FFN sub-blocks run under the step's named regions
+    # (repro.obs.scopes); the recurrent mixers take none
+    mixer = jax.named_scope("attention") if kind == "attn" \
+        else contextlib.nullcontext()
+    with mixer:
+        h = L.norm_apply(sp["norm1"], x)
+        window = cfg.sliding_window
+        if kind == "attn":
+            out, (k, v) = L.attention_apply(sp["attn"], h, cfg,
+                                            rope_cs=rope_cs, causal=True,
+                                            window=window)
+            if collect_cache:
+                cache["k"], cache["v"] = k, v
+        elif kind == "mamba":
+            out, st = L.mamba_apply(sp["mamba"], h, cfg)
+            if collect_cache:
+                cache["conv"], cache["ssm"] = st
+        elif kind == "mlstm":
+            out, st = L.mlstm_apply(sp["mlstm"], h, cfg)
+            if collect_cache:
+                cache["C"], cache["n"] = st
+        elif kind == "slstm":
+            out, st = L.slstm_apply(sp["slstm"], h, cfg)
+            if collect_cache:
+                cache["h"], cache["c"], cache["sn"], cache["m"] = st
+        x = L.residual_add(x, out)
     if cfg.enc_dec and enc_out is not None:
-        hx = L.norm_apply(sp["norm_x"], x)
-        outx, (ck, cv) = L.attention_apply(sp["cross"], hx, cfg,
-                                           kv_override=enc_out)
-        x = L.residual_add(x, outx)
+        with jax.named_scope("attention"):
+            hx = L.norm_apply(sp["norm_x"], x)
+            outx, (ck, cv) = L.attention_apply(sp["cross"], hx, cfg,
+                                               kv_override=enc_out)
+            x = L.residual_add(x, outx)
         if collect_cache:
             cache["cross_k"], cache["cross_v"] = ck, cv
     if has_ffn:
-        h2 = L.norm_apply(sp["norm2"], x)
-        if is_moe:
-            out2, a = L.moe_apply(sp["moe"], h2, cfg.moe,
-                                  groups=cfg.moe_dispatch_groups or 1,
-                                  gather_weights=cfg.moe_gather_weights)
-            aux = {k2: aux[k2] + a[k2] for k2 in aux}
-        else:
-            out2 = L.mlp_apply(sp["mlp"], h2)
-        x = L.residual_add(x, out2)
+        with jax.named_scope("mlp"):
+            h2 = L.norm_apply(sp["norm2"], x)
+            if is_moe:
+                out2, a = L.moe_apply(sp["moe"], h2, cfg.moe,
+                                      groups=cfg.moe_dispatch_groups or 1,
+                                      gather_weights=cfg.moe_gather_weights)
+                aux = {k2: aux[k2] + a[k2] for k2 in aux}
+            else:
+                out2 = L.mlp_apply(sp["mlp"], h2)
+            x = L.residual_add(x, out2)
     return x, aux, (cache if collect_cache else None)
 
 
@@ -289,7 +299,6 @@ def forward_groups(cfg, groups_params, x, *, rope_cs, enc_out=None,
     """
     slots = slot_spec(cfg)
     g1 = n_groups(cfg) if g1 is None else g1
-    sub = jax.tree_util.tree_map(lambda a: a[g0:g1], groups_params)
 
     def body(carry, pgroup):
         x, lb, z = carry
@@ -308,8 +317,10 @@ def forward_groups(cfg, groups_params, x, *, rope_cs, enc_out=None,
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
-    zero = jnp.zeros((), jnp.float32)
-    (x, lb, z), caches = jax.lax.scan(body, (x, zero, zero), sub)
+    with jax.named_scope("layers"):
+        sub = jax.tree_util.tree_map(lambda a: a[g0:g1], groups_params)
+        zero = jnp.zeros((), jnp.float32)
+        (x, lb, z), caches = jax.lax.scan(body, (x, zero, zero), sub)
     return x, {"lb_loss": lb, "z_loss": z}, caches
 
 

@@ -12,7 +12,10 @@ Three pillars (full guide: docs/OBSERVABILITY.md):
   checkpoint saves/restores, fault sightings/recoveries).
 * **Spans** (``obs.trace``) — host-walltime timelines exportable as Chrome
   trace-event JSON for Perfetto (trainer phases, per-stage executor ticks,
-  request lifecycles).
+  request lifecycles), each also a ``jax.profiler`` annotation.
+* **Scopes** (``obs.scopes``) — the named regions of the step program and
+  the compiled step's instruction -> ``op_name`` table, which charges a
+  device trace's operations to them.
 
 Consumption: ``launch/loadgen.py`` (open-loop Poisson load against the
 serve engine, SLOs into ``results/BENCH_9.json``) and

@@ -7,7 +7,10 @@ integer tracks (``tid``s).  Three ways in:
 * ``with tracer.span("tick 3", cat="stage", tid=1, stage=0):`` — timed
   around a block (the executor wraps each stage's tick *dispatch*; on an
   accelerator that is dispatch latency, not device compute — the span
-  marks when work was issued and in what order).
+  marks when work was issued and in what order).  The block also runs
+  inside a ``jax.profiler.TraceAnnotation`` of the same name, so the span
+  shows on the host plane of any ``jax.profiler`` trace taken meanwhile,
+  on the profiler's clock, beside the device's operations.
 * ``tracer.add_span(name, ts, dur, ...)`` — retroactive, for lifecycle
   spans whose start was recorded earlier (the engine's queued/active
   request spans).
@@ -29,6 +32,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # track-id convention (see module docstring)
 TID_LOOP = 0
@@ -76,7 +81,8 @@ class Tracer:
              **args):
         t0 = self.now()
         try:
-            yield
+            with TraceAnnotation(name):
+                yield
         finally:
             self.add_span(name, t0, self.now() - t0, cat=cat, tid=tid,
                           **args)

@@ -31,6 +31,7 @@ from repro import runtime
 from repro.core import losses, partition, sil as sil_lib
 from repro.models import mlp as MLP
 from repro.models import model as M
+from repro.obs import scopes
 from repro.optim import make_optimizer, mixed_precision, step_guard
 
 from repro.train.spec import StageSpec, TrainSpec
@@ -468,10 +469,11 @@ class LMBackend:
     def _objective(self, last_params, h, labels, aux, mask):
         """CE (+ MoE aux) of the last stage's final hidden states ``h``,
         chunked over tokens so the whole logits never exist at once."""
-        return losses.chunked_train_objective(
-            self.cfg, functools.partial(partition.stage_unembed, self.cfg,
-                                        last_params),
-            self._trim_vision(h), labels, aux, mask)
+        with jax.named_scope("ce"):
+            return losses.chunked_train_objective(
+                self.cfg, functools.partial(partition.stage_unembed,
+                                            self.cfg, last_params),
+                self._trim_vision(h), labels, aux, mask)
 
     def _jit_step(self, step):
         return jax.jit(step, donate_argnums=donate_argnums(0, 1))
@@ -511,7 +513,12 @@ class LMBackend:
                                                  accum))
 
         def call(sp, st, xin, labels, mask=None):
-            return jitted(sp, st, xin, labels, mask, sil)
+            args = (sp, st, xin, labels, mask, sil)
+            if not call.registered:
+                call.registered = scopes.register(f"lm_stage_step.{k}",
+                                                  jitted, args)
+            return jitted(*args)
+        call.registered = False
         return call
 
     def _stage_step(self, k: int, opt, stage_params_struct, accum: int):
@@ -546,7 +553,8 @@ class LMBackend:
                 grads = jax.tree_util.tree_map(
                     lambda g, s: jax.lax.with_sharding_constraint(g, s),
                     grads, pspecs)
-            new_train, st2 = opt.update(grads, st, train)
+            with jax.named_scope("optimizer"):
+                new_train, st2 = opt.update(grads, st, train)
             return {**new_train, **frozen}, st2, loss / scale
 
         return step
@@ -571,14 +579,20 @@ class LMBackend:
         enc_dec = self.cfg.enc_dec
 
         def step(sp, st, labels, sil_in, sil_target):
-            syn = sil_lib.sil_lookup(sil_in, labels).astype(act)
+            with jax.named_scope("sil_input"):
+                syn = sil_lib.sil_lookup(sil_in, labels).astype(act)
             xin = (syn, None) if enc_dec else syn
             return inner(sp, st, xin, labels, None, sil_target)
 
         jitted = self._jit_step(step)
 
         def call(sp, st, labels):
-            return jitted(sp, st, labels, sil_in, sil_target)
+            args = (sp, st, labels, sil_in, sil_target)
+            if not call.registered:
+                call.registered = scopes.register(
+                    f"lm_parallel_stage_step.{k}", jitted, args)
+            return jitted(*args)
+        call.registered = False
         return call
 
     def build_recovery_step(self, j: int, frozen_stages: list, opt,
@@ -609,7 +623,8 @@ class LMBackend:
                 return loss * scale
             loss, grads = value_and_accum_grads(loss_fn, train, (batch,),
                                                 accum)
-            new_train, st2 = opt.update(grads, st, train)
+            with jax.named_scope("optimizer"):
+                new_train, st2 = opt.update(grads, st, train)
             return {**new_train, **snap}, st2, loss / scale
 
         jitted = self._jit_step(step)

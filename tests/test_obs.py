@@ -248,6 +248,26 @@ def test_write_chrome_trace_is_valid_json(tmp_path):
         assert json.load(f)["traceEvents"][0]["name"] == "x"
 
 
+def test_span_is_on_the_profilers_host_plane(tmp_path):
+    """A span taken while jax.profiler traces shows by its name on the
+    trace's host plane, on the profiler's clock."""
+    import glob
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("tick 7", cat="stage", tid=TID_STAGE0, stage=0):
+            jnp.ones(8).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for e in line.events}
+    assert "tick 7" in names
+    assert [s.name for s in tr.spans] == ["tick 7"]
+
+
 # ==========================================================================
 # scheduler <-> event log mapping (exactly once), open-loop take
 # ==========================================================================
@@ -669,3 +689,176 @@ def test_metrics_cli_check_fails_on_violations(tmp_path):
     with open(path, "w") as f:
         json.dump(bad, f)
     assert metrics_main(["--check", path]) == 1
+
+
+# ==========================================================================
+# named regions of the step program (repro.obs.scopes)
+# ==========================================================================
+
+_LAYER = "jit(step)/transpose(jvp(layers))/while/body/closed_call/checkpoint"
+
+
+@pytest.mark.parametrize("op_name,names,pass_", [
+    ("jit(step)/jvp(layers)/while/body/closed_call/mlp/dot_general",
+     ("layers", "mlp"), "forward"),
+    (_LAYER + "/rematted_computation/attention/flash/pallas_call",
+     ("layers", "attention", "flash"), "remat"),
+    (_LAYER + "/mlp/transpose", ("layers", "mlp"), "backward"),
+    # the custom VJP's body, and the recomputation inside it
+    (_LAYER + "/attention/flash/jvp(jit(chunked_attention))/while/body/exp",
+     ("layers", "attention", "flash"), "backward"),
+    (_LAYER + "/attention/flash/flash/transpose(jvp(jit(chunked_attention)))"
+     "/while/body/closed_call/checkpoint/rematted_computation/exp",
+     ("layers", "attention", "flash"), "backward"),
+    ("jit(step)/transpose(jvp(ce))/while/body/reduce_max", ("ce",),
+     "backward"),
+    ("jit(step)/optimizer/mul", ("optimizer",), "forward"),
+    # a jitted function's name is no scope, even one spelled like a scope
+    ("jit(step)/sil_mse/jit(sil_mse)/dot_general", ("sil_mse",), "forward"),
+    ("jit(step)/jit(sil_mse)/dot_general", (), "forward"),
+    ("jit(step)/transpose(jvp())/mul", (), "backward"),
+    ("jit(step)/jvp(layers)/while/body/dynamic_update_slice", ("layers",),
+     "forward"),
+])
+def test_scope_path(op_name, names, pass_):
+    from repro.obs.scopes import scope_path
+    assert scope_path(op_name) == (names, pass_)
+
+
+_HLO = """HloModule jit_step, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
+
+%fused_computation (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  %exp.1 = f32[8]{0} exponential(%param_0), metadata={op_name="jit(step)/mlp/exp"}
+  ROOT %convert.2 = f32[8]{0} convert(%exp.1)
+}
+
+%body (arg: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %arg = (s32[], f32[8]{0}) parameter(0)
+  %copy.3 = f32[8]{0} copy(%arg)
+  %named.4 = f32[8]{0} add(%copy.3, %copy.3), metadata={op_name="jit(step)/jvp(layers)/while/body/attention/add"}
+  ROOT %tuple.5 = (s32[], f32[8]{0}) tuple(%arg, %named.4)
+}
+
+%cond (arg.1: (s32[], f32[8])) -> pred[] {
+  %arg.1 = (s32[], f32[8]{0}) parameter(0)
+  ROOT %lt.6 = pred[] compare(%arg.1, %arg.1), direction=LT
+}
+
+ENTRY %main.7 (x.1: f32[8]) -> f32[8] {
+  %x.1 = f32[8]{0} parameter(0)
+  %copy.8 = f32[8]{0} copy(%x.1)
+  %while.9 = (s32[], f32[8]{0}) while(%copy.8), condition=%cond, body=%body, metadata={op_name="jit(step)/jvp(layers)/while"}
+  ROOT %fusion.10 = f32[8]{0} fusion(%while.9), kind=kLoop, calls=%fused_computation
+}
+"""
+
+
+def test_hlo_op_names_fill_gaps_from_fused_root_and_caller():
+    from repro.obs.scopes import hlo_op_names
+    table = hlo_op_names(_HLO)
+    assert table["named.4"] == \
+        "jit(step)/jvp(layers)/while/body/attention/add"
+    # unnamed in the loop body and condition: the while's op_name
+    assert table["copy.3"] == table["lt.6"] == "jit(step)/jvp(layers)/while"
+    # a fusion whose root the compiler made: its last named instruction
+    assert table["fusion.10"] == "jit(step)/mlp/exp"
+    # unnamed in the entry computation: left out
+    assert "copy.8" not in table and "x.1" not in table
+
+
+def _three_stage_lm():
+    """A 3-stage tiny LM: stage 0, one interior stage and the last."""
+    from repro.configs import get
+    from repro.core import partition
+    from repro.models import model as M
+    from repro.train.backends import LMBackend, make_optimizer_for
+    from repro.train.spec import StageSpec, TrainSpec
+    cfg = get("qwen2-1.5b", smoke=True).replace(n_layers=3)
+    plan = partition.make_plan(cfg, 3)
+    spec = TrainSpec(n_stages=3, kappa=1.0, stages=tuple(
+        StageSpec(steps=1, lr=1e-3, optimizer="adamw") for _ in range(3)))
+    be = LMBackend(cfg, plan, None, spec)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    sils = be.make_sils(jax.random.PRNGKey(1), spec.kappa)
+    opt = make_optimizer_for(spec.stage(0), spec)
+    toks = jax.random.randint(jax.random.PRNGKey(2), (2, 32), 0,
+                              cfg.vocab_size)
+    return be, be.split(params), sils, opt, {"tokens": toks, "labels": toks}
+
+
+def _run_stage(be, sps, sils, opt, batch, k):
+    """Build stage k's step as a deployment does and take one step."""
+    sp = jax.device_put(sps[k])
+    st = opt.init(be.trainable(sp))
+    if k == 0:
+        step = be.build_stage_step(0, opt, sils[0])
+        step(sp, st, batch, batch["labels"])
+        return step, "lm_stage_step.0"
+    target = sils[k] if k < be.n_stages - 1 else None
+    step = be.build_parallel_stage_step(k, opt, sils[k - 1], target)
+    step(sp, st, batch["labels"])
+    return step, f"lm_parallel_stage_step.{k}"
+
+
+@pytest.mark.parametrize("k,ran", [
+    (0, {"embed", "layers", "attention", "flash", "mlp", "sil_mse",
+         "optimizer"}),
+    (1, {"sil_input", "layers", "attention", "flash", "mlp", "sil_mse",
+         "optimizer"}),
+    (2, {"sil_input", "layers", "attention", "flash", "mlp", "ce",
+         "optimizer"}),
+])
+def test_op_scopes_of_a_stage_step(k, ran):
+    """Every region the step runs is in its compiled program's table; the
+    attention core shows its forward, its recomputation and its backward,
+    and the optimizer runs after the backward."""
+    from repro.obs import scopes
+    world = _three_stage_lm()
+    _, name = _run_stage(*world, k)
+    table = scopes.op_scopes(name)
+    assert table is scopes.op_scopes(name)       # compiled once
+    seen = {}
+    for op_name in table.values():
+        names, pass_ = scopes.scope_path(op_name)
+        for n in names:
+            seen.setdefault(n, set()).add(pass_)
+    assert set(seen) == ran
+    assert seen["flash"] == {"forward", "remat", "backward"}
+    assert seen["optimizer"] == {"forward"}
+
+
+def test_register_records_once_and_op_scopes_follows_the_latest(monkeypatch):
+    from repro.obs import scopes
+    monkeypatch.setattr(scopes, "_PROGRAMS", {})
+    with pytest.raises(LookupError):
+        scopes.op_scopes()
+    calls = []
+    real = scopes.register
+    monkeypatch.setattr(scopes, "register",
+                        lambda *a: calls.append(a[0]) or real(*a))
+    be, sps, sils, opt, batch = _three_stage_lm()
+    step, name = _run_stage(be, sps, sils, opt, batch, 0)
+    sp = jax.device_put(sps[0])
+    step(sp, opt.init(be.trainable(sp)), batch, batch["labels"])
+    assert calls == [name] and list(scopes._PROGRAMS) == [name]
+    # shapes only: the registry holds no device array of the step
+    args = scopes._PROGRAMS[name].args
+    assert not any(isinstance(x, jax.Array)
+                   for x in jax.tree_util.tree_leaves(args))
+    first = scopes.op_scopes()
+    assert first is scopes.op_scopes(name)
+    # a call under a transformation is not the program that runs
+    last = be.build_parallel_stage_step(2, opt, sils[1], None)
+    sp2 = jax.device_put(sps[2])
+    st2 = opt.init(be.trainable(sp2))
+    jax.make_jaxpr(last)(sp2, st2, batch["labels"])
+    assert list(scopes._PROGRAMS) == [name]
+    last(sp2, st2, batch["labels"])
+    assert list(scopes._PROGRAMS) == [name, "lm_parallel_stage_step.2"]
+    latest = scopes.op_scopes()
+    assert latest is scopes.op_scopes("lm_parallel_stage_step.2")
+    assert any("ce" in scopes.scope_path(o)[0] for o in latest.values())
+    assert not any("ce" in scopes.scope_path(o)[0] for o in first.values())
+    with pytest.raises(LookupError):
+        scopes.op_scopes("no_such_step")

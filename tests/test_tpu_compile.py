@@ -127,3 +127,62 @@ def test_reference_attention_grad_compiles(one_chip):
     def loss(q, k, v):
         return fa_ref.chunked_attention(q, k, v).astype(jnp.float32).sum()
     _compile(jax.grad(loss, (0, 1, 2)), one_chip, *QWEN_QKV)
+
+
+def test_stage_step_scopes_name_the_flash_kernel_and_its_backward(
+        one_chip, monkeypatch):
+    """A qwen-shaped stage-0 SIL step (head_dim 128, GQA, QKV bias; one
+    layer at width 256) compiled for the chip: the step's table puts the
+    Pallas forward in ``flash`` on the forward pass and again as the
+    layer's recomputation, and the custom VJP's jnp ops in ``flash`` on the
+    backward pass."""
+    from repro.configs.base import ModelConfig
+    from repro.core import partition
+    from repro.kernels import dispatch
+    from repro.kernels.sil_mse import kernel as sk
+    from repro.obs import scopes
+    from repro.train.backends import LMBackend, make_optimizer_for
+    from repro.train.spec import StageSpec, TrainSpec
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "flash_attention_tpu", functools.partial(
+        fa.flash_attention_tpu, interpret=False))
+    monkeypatch.setattr(sk, "sil_mse_fwd_tpu", functools.partial(
+        sk.sil_mse_fwd_tpu, interpret=False))
+    dispatch.cache_clear()
+    cfg = ModelConfig(name="qwen-shaped", family="dense", n_layers=4,
+                      d_model=256, n_heads=2, n_kv_heads=1, d_ff=512,
+                      vocab_size=1024, head_dim=128, qkv_bias=True,
+                      norm="rmsnorm", mlp_type="swiglu",
+                      tie_embeddings=True, source="test")
+    hp = StageSpec(steps=1, lr=1e-3, optimizer="adamw")
+    spec = TrainSpec(n_stages=2, kappa=1.0, precision="bf16",
+                     stages=(hp, hp))
+    be = LMBackend(cfg, partition.make_plan(cfg, 2), None, spec)
+    opt = make_optimizer_for(hp, spec)
+    step = jax.jit(be._stage_step(0, opt, None, 1))
+
+    def struct(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    from repro.models import model as M
+    params = jax.eval_shape(lambda: be.split(M.init_params(
+        cfg, jax.random.PRNGKey(0)))[0])
+    sp = struct(params)
+    st = struct(jax.eval_shape(opt.init, be.trainable(params)))
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+    sil = jax.ShapeDtypeStruct((256, 1024), jnp.float32, sharding=one_chip)
+    try:
+        text = step.lower(sp, st, {"tokens": tokens, "labels": tokens},
+                          tokens, None, sil).compile().as_text()
+    finally:
+        dispatch.cache_clear()
+    table = scopes.hlo_op_names(text)
+    kernels = [scopes.scope_path(op) for instr, op in table.items()
+               if instr.startswith("flash_attention_tpu")]
+    assert sorted(p for names, p in kernels if "flash" in names) \
+        == ["forward", "remat"]
+    backward = [instr for instr, op in table.items()
+                if scopes.scope_path(op) == (("layers", "attention", "flash"),
+                                             "backward")]
+    assert backward
+    assert not any(i.startswith("flash_attention_tpu") for i in backward)
