@@ -3,8 +3,23 @@
 Blocking: grid (batch, q_heads, Sq/BQ, Sk/BK); the KV axis is the minor-most
 grid dim, iterated sequentially per TPU core, so the online-softmax running
 state (m, l, acc) lives in VMEM scratch across KV steps.  Q/K/V blocks are
-(BQ, D) / (BK, D) VMEM tiles (BQ = BK = 128, MXU-aligned; head_dim of the
-assigned archs is 64..384 so a (128, D) tile is <= 192 KiB).
+(BQ, D) / (BK, D) VMEM tiles.  ``plan()`` chooses the tiles from the
+shapes: 512 rows, the sequence padded to a multiple of 512, or one tile
+over a shorter sequence.
+
+Causal and windowed calls visit only the KV blocks some row of the q block
+can see.  The K/V index maps clamp the KV step into that range, so a
+skipped step keeps the block index of the step before it and the pipeline
+issues no DMA for it; the kernel body skips its compute.  Only blocks that
+cross the diagonal, the window's edge or the padding apply a mask.
+Non-causal calls (encoder and cross attention, Sq != Sk) run the full grid.
+A causal q is aligned to the end of k, as in ``ref.py``.
+
+The MXU takes the operands in the input dtype (bf16 under a bf16 policy)
+with fp32 results; the scale applies to the fp32 scores, and the scores,
+softmax statistics, accumulator and the final divide stay fp32.  ``m`` and
+``l`` are kept lane-replicated as (BQ, 128), so no step relayouts a 1-D
+vector.
 
 GQA is handled in the index map: query head h reads KV head h // (H // KV) —
 KV is never materialized per-Q-head.  Validated against ref.py in
@@ -28,25 +43,56 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.plan import (BlockPlan, KernelPlan, ScalarPrefetchPlan,
                                 ScratchPlan, as_block_spec, as_scratch)
 
-DEFAULT_BQ = 128
-DEFAULT_BK = 128
+DEFAULT_BK = 128      # the decode kernels' cache tile
 NEG_INF = -1e30
+LANES = 128
+MAX_BLOCK = 512       # the flash forward's largest sequence tile
 
 
-def plan(b, sq, sk, h, kv, d, *, bq=DEFAULT_BQ, bk=DEFAULT_BK,
+def _seq_block(s, align):
+    """The flash tile along a sequence axis of length ``s``: ``MAX_BLOCK``,
+    or the whole axis padded to ``align`` where it is shorter.  On a v5e a
+    512 x 512 step takes about twice the time of a 128 x 128 one for 16
+    times the work, so padding a sequence to a multiple of 512 costs less
+    than cutting it into smaller tiles."""
+    return min(MAX_BLOCK, s + (-s) % align)
+
+
+def _kv_bounds(iq, *, bq, bk, nk, off, causal, window):
+    """First and last KV block that some row of q block ``iq`` sees; q row
+    r sits at key position r + ``off``.  Python ints in (the static
+    checker's grid corners), concrete values out; traced ids in, traced
+    values out."""
+    lo, hi = 0, nk - 1
+    if window:
+        lo = jnp.maximum(iq * bq + off - window + 1, 0) // bk
+    if causal:
+        hi = jnp.minimum(((iq + 1) * bq - 1 + off) // bk, nk - 1)
+    return lo, hi
+
+
+def plan(b, sq, sk, h, kv, d, *, bq=None, bk=None, causal=True, window=0,
          dtype="float32") -> KernelPlan:
     """Launch geometry for ``flash_attention_tpu`` over logical shapes
     q:(b,sq,h,d), k/v:(b,sk,kv,d).  Arrays are transposed to head-major and
     padded to block multiples before the call; the plan describes those
-    padded layouts."""
+    padded layouts.  ``bq``/``bk`` default to ``_seq_block``'s tiles; the
+    K/V maps clamp the KV step into ``_kv_bounds``, so skipped steps fetch
+    nothing new."""
     g = h // kv
-    bq_ = min(bq, sq)
-    bk_ = min(bk, sk)
+    bq_ = min(bq, sq) if bq else _seq_block(sq, 8)
+    bk_ = min(bk, sk) if bk else _seq_block(sk, LANES)
     sq_p = sq + (-sq) % bq_
     sk_p = sk + (-sk) % bk_
     nq = sq_p // bq_
     nk = sk_p // bk_
-    kv_map = lambda b_, h_, iq, ik: (b_, h_ // g, ik, 0)  # noqa: E731
+    bounds = functools.partial(_kv_bounds, bq=bq_, bk=bk_, nk=nk,
+                               off=sk - sq, causal=causal, window=window)
+
+    def kv_map(b_, h_, iq, ik):
+        lo, hi = bounds(iq)
+        return b_, h_ // g, jnp.maximum(jnp.minimum(ik, hi), lo), 0
+
     return KernelPlan(
         family="flash_attention", entry="flash_attention",
         grid=(b, h, nq, nk),
@@ -63,8 +109,8 @@ def plan(b, sq, sk, h, kv, d, *, bq=DEFAULT_BQ, bk=DEFAULT_BK,
                       (b, h, sq_p, d), dtype),
         ),
         scratch=(
-            ScratchPlan("m", (bq_,), "float32", accumulator=True),
-            ScratchPlan("l", (bq_,), "float32", accumulator=True),
+            ScratchPlan("m", (bq_, LANES), "float32", accumulator=True),
+            ScratchPlan("l", (bq_, LANES), "float32", accumulator=True),
             ScratchPlan("acc", (bq_, d), "float32", accumulator=True),
         ),
     )
@@ -154,10 +200,23 @@ def paged_decode_plan(b, nb, bs, h, kv, d, *, n_blocks,
     )
 
 
+def _lanes(x, n):
+    """A (rows, 128) lane-replicated column as (rows, n), without a
+    cross-lane broadcast where n is a multiple of 128 or below it."""
+    if n == LANES:
+        return x
+    if n % LANES == 0:
+        return jnp.tile(x, (1, n // LANES))
+    if n < LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  scale, causal, window, bq, bk, sk):
+                  scale, causal, window, bq, bk, sk, off):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
+    nk = pl.num_programs(3)
 
     @pl.when(ik == 0)
     def _init():
@@ -165,50 +224,65 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (BQ, D)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (BK, D)
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (BQ, BK)
+    def step(masked):
+        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            qpos = (iq * bq + off
+                    + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
+            kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            mask = kpos < sk
+            if causal:
+                mask &= kpos <= qpos
+            if window:
+                mask &= kpos > qpos - window
+            s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[...]                                   # (BQ, 128)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, bk))
+        if masked:
+            p = jnp.where(mask, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        v = v_ref[0, 0]
+        acc_ref[...] = (acc_ref[...] * _lanes(corr, acc_ref.shape[1])
+                        + jax.lax.dot(p.astype(v.dtype), v,
+                                      preferred_element_type=jnp.float32))
+        m_ref[...] = m_new
 
-    qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = kpos < sk
+    lo, hi = _kv_bounds(iq, bq=bq, bk=bk, nk=nk, off=off, causal=causal,
+                       window=window)
+    run = (ik >= lo) & (ik <= hi)
+    # every row sees every key of the block: no mask to apply
+    whole = (ik + 1) * bk <= sk
     if causal:
-        mask &= kpos <= qpos
+        whole &= (ik + 1) * bk - 1 <= iq * bq + off
     if window:
-        mask &= kpos > qpos - window
-    s = jnp.where(mask, s, NEG_INF)
+        whole &= ik * bk > (iq + 1) * bq - 1 + off - window
+    pl.when(run & whole)(lambda: step(masked=False))
+    pl.when(run & jnp.logical_not(whole))(lambda: step(masked=True))
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(mask, p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(ik == pl.num_programs(3) - 1)
+    @pl.when(ik == nk - 1)
     def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
+        denom = _lanes(jnp.maximum(l_ref[...], 1e-30), acc_ref.shape[1])
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                              "interpret"))
-def flash_attention_tpu(q, k, v, *, causal=True, window=0, bq=DEFAULT_BQ,
-                        bk=DEFAULT_BK, interpret=None):
-    """q: (B, Sq, H, D); k, v: (B, Sk, KV, D) -> (B, Sq, H, D)."""
+def flash_attention_tpu(q, k, v, *, causal=True, window=0, bq=None, bk=None,
+                        interpret=None):
+    """q: (B, Sq, H, D); k, v: (B, Sk, KV, D) -> (B, Sq, H, D).  ``bq``/``bk``
+    override ``plan()``'s tiles."""
     b, sq, h, d = q.shape
     sk, kv = k.shape[1], k.shape[2]
-    g = h // kv
     scale = d ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    kp = plan(b, sq, sk, h, kv, d, bq=bq, bk=bk, dtype=str(q.dtype))
+    kp = plan(b, sq, sk, h, kv, d, bq=bq, bk=bk, causal=causal,
+              window=window, dtype=str(q.dtype))
     bq_ = kp.inputs[0].block_shape[2]
     bk_ = kp.inputs[1].block_shape[2]
     pad_q = kp.inputs[0].array_shape[2] - sq
@@ -224,7 +298,8 @@ def flash_attention_tpu(q, k, v, *, causal=True, window=0, bq=DEFAULT_BQ,
         vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
 
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                               window=window, bq=bq_, bk=bk_, sk=sk)
+                               window=window, bq=bq_, bk=bk_, sk=sk,
+                               off=sk - sq)
     out = pl.pallas_call(
         kernel,
         grid=kp.grid,

@@ -17,6 +17,7 @@ KEY = jax.random.PRNGKey(0)
 @pytest.mark.parametrize("b,s,h,kv,d", [
     (2, 256, 4, 2, 64), (1, 128, 4, 4, 64), (2, 200, 8, 2, 128),
     (1, 384, 6, 6, 64), (1, 96, 12, 2, 128),
+    (1, 1100, 4, 2, 128), (1, 1536, 8, 2, 64),   # several 512 tiles
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 64), (False, 0)])
@@ -31,6 +32,60 @@ def test_flash_attention_sweep(b, s, h, kv, d, dtype, causal, window):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal,window", [(False, 0), (True, 0),
+                                           (True, 200)])
+def test_flash_attention_q_shorter_than_k(dtype, causal, window):
+    """Cross attention (96 queries over 700 keys: a 96-row q tile over two
+    512-key tiles, the second padded); causal calls align q to the end of
+    k, as the reference does."""
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (2, 96, 4, 64), dtype)
+    k = jax.random.normal(ks[1], (2, 700, 2, 64), dtype)
+    v = jax.random.normal(ks[2], (2, 700, 2, 64), dtype)
+    out = flash_attention_tpu(q, k, v, causal=causal, window=window)
+    exp = fa_ref.naive_attention(q, k, v, causal=causal, window=window)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(exp, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("s,window", [(4096, 0), (1100, 0), (4096, 1000)])
+def test_flash_plan_fetches_only_visible_kv_blocks(s, window):
+    """The K/V index maps of a causal plan, over every (iq, ik) step, visit
+    the KV blocks that q block iq can see in order and then repeat the last
+    one, so a skipped step fetches nothing: the distinct (iq, kv block)
+    fetches are the causal lower triangle (cut by the window)."""
+    from repro.kernels.flash_attention.kernel import plan
+    kp = plan(2, s, s, 12, 2, 128, causal=True, window=window,
+              dtype="bfloat16")
+    q_bp, k_bp, v_bp = kp.inputs
+    bq, bk = q_bp.block_shape[2], k_bp.block_shape[2]
+    _, _, nq, nk = kp.grid
+    fetched = set()
+    for iq in range(nq):
+        rows = range(iq * bq, min((iq + 1) * bq, s))
+        first = max(rows[0] - window + 1, 0) // bk if window else 0
+        last = rows[-1] // bk
+        seen = []
+        for ik in range(nk):
+            idx = tuple(int(i) for i in k_bp.index_map(1, 5, iq, ik))
+            assert idx == tuple(int(i) for i in v_bp.index_map(1, 5, iq, ik))
+            assert idx[:2] == (1, 5 // 6) and idx[3] == 0
+            assert first <= idx[2] <= last
+            seen.append(idx[2])
+        visible = list(range(first, last + 1))
+        assert seen == [first] * first + visible + [last] * (nk - 1 - last)
+        fetched |= {(iq, j) for j in seen}
+    need = {(iq, j) for iq in range(nq) for j in range(nk)
+            if j * bk <= min((iq + 1) * bq, s) - 1
+            and (not window or (j + 1) * bk - 1 > iq * bq - window)}
+    assert fetched == need
+    if not window and bq == bk:
+        assert len(fetched) == nq * (nq + 1) // 2
 
 
 @pytest.mark.parametrize("b,lc,h,kv,d", [

@@ -5,7 +5,8 @@ and refuses what the chip would refuse (blocks not aligned to the (8, 128)
 tiling, too much VMEM, programs that do not fit).  Interpret mode on the
 CPU cannot see any of that.  Shapes are the published widths: the paper's
 MLP boundary and qwen2-1.5b (d_model 1536, 12 heads, 2 KV heads, head_dim
-128, vocab 151936) at sequence 4096.
+128, vocab 151936) at sequence 4096, and chatglm3-6b's attention (32 heads
+over 2 KV groups of 128).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -69,6 +70,15 @@ QWEN_QKV = (((1, 4096, 12, 128), BF16), ((1, 4096, 2, 128), BF16),
 def test_flash_forward_compiles(one_chip):
     hlo = _compile(functools.partial(fa.flash_attention_tpu,
                                      interpret=False), one_chip, *QWEN_QKV)
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_forward_compiles_at_chatglm3_width(one_chip):
+    """chatglm3-6b's attention: 32 query heads over 2 KV groups of 128."""
+    hlo = _compile(functools.partial(fa.flash_attention_tpu,
+                                     interpret=False), one_chip,
+                   ((2, 4096, 32, 128), BF16), ((2, 4096, 2, 128), BF16),
+                   ((2, 4096, 2, 128), BF16))
     assert "tpu_custom_call" in hlo
 
 
