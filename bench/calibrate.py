@@ -5,8 +5,9 @@
         [--faults 3] [--first-seed N] [--out FILE]
 
 For each seed: the program's first steps through the timed call and feed
-(``StageRun.first_steps``) against the float32 reference, giving the sound
-runs' readings of ``loss_gap``, ``grad_gap`` and ``change_gap``.  For the
+(the run's ``first_steps``; every stage at once where the cell runs them
+all) against the float32 reference, giving the sound runs' readings of
+``loss_gap``, ``grad_gap`` and ``change_gap`` of each stage.  For the
 first ``--control`` seeds also the control, the reference computed with
 float8 matmul operands in the program's place, and for the first
 ``--faults`` seeds the program fed half of each batch, the mean taken over
@@ -35,24 +36,14 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
 
-    from bench import check, harness, reference
-    from bench import model as bm
-    from bench.stage import StageRun
-    from bench.traffic import load_traffic
+    from bench import check, harness
 
-    bench = harness.load_benchmark(ROOT)
-    cell = harness.workload(bench, args.workload)
-    cfg = bm.load_config(os.path.join(ROOT, harness.config_entry(
-        bench, cell["config"])["file"]))
-    traffic = load_traffic(harness.bench_file(
-        ROOT, "traffic", f"{cell['traffic']}.json"), cell["traffic"])
-    _, loss_steps = check.load_limits(harness.bench_file(
-        ROOT, "limits", f"{args.workload}.json"))
+    cell = harness.load_cell(ROOT, args.workload)
     import jax
     harness.configure_jax_cache(ROOT)
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print(f"error: no TPU ({dev.platform})", file=sys.stderr)
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(f"error: no TPU ({devices[0].platform})", file=sys.stderr)
         return 3
     out = open(args.out, "a") if args.out else None
 
@@ -63,34 +54,36 @@ def main(argv=None) -> int:
             out.write(line + "\n")
             out.flush()
 
-    n = traffic.check_steps
     for i in range(args.seeds):
         seed = args.first_seed + 7919 * i
         t0 = time.perf_counter()
-        run = StageRun(cfg, traffic, seed)
+        run = harness.make_run(cell, seed, devices)
         prog = run.first_steps()
-        batches = run.batches
+        stages, chips, batches = run.stages, run.devices, run.batches
         run.free()
         t1 = time.perf_counter()
-        ref = reference.readings(cfg, run.k, batches, seed, n)
+        ref = harness.reference_readings(cell, stages, chips, batches, seed)
         t2 = time.perf_counter()
         readings = {"program": prog}
         if i < args.faults:
-            half = StageRun(cfg, traffic, seed, half_batch=True)
+            half = harness.make_run(cell, seed, devices, half_batch=True)
             readings["half_batch"] = half.first_steps()
             half.free()
         if i < args.control:
-            readings["control"] = reference.readings(cfg, run.k, batches,
-                                                     seed, n, precision="fp8")
+            readings["control"] = harness.reference_readings(
+                cell, stages, chips, batches, seed, precision="fp8")
         for kind, r in readings.items():
-            nums = check.numbers(r, ref, loss_steps)
+            nums = harness.compare(cell, r, ref)
             emit({"cell": args.workload, "seed": seed, "kind": kind,
                   **{k: v for k, (v, _) in nums.items()},
                   "at": {k: w for k, (_, w) in nums.items()},
-                  "losses": r["losses"], "ref_losses": ref["losses"],
-                  "leaves": len(ref["grad"]),
-                  "left_out": sorted(set(ref["grad"])
-                                     - set(check.moved_leaves(ref))),
+                  "stages": {cell.prefix(k) or str(k): {
+                      "losses": r[k]["losses"],
+                      "ref_losses": ref[k]["losses"],
+                      "leaves": len(ref[k]["grad"]),
+                      "left_out": sorted(set(ref[k]["grad"])
+                                         - set(check.moved_leaves(ref[k])))}
+                      for k in stages},
                   "program_s": round(t1 - t0, 3),
                   "reference_s": round(t2 - t1, 3)})
     return 0

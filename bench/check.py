@@ -13,8 +13,9 @@ reference's over the first steps of the very object the window then drives:
   thousandth of the median leaf's (a key's bias has no gradient under
   softmax; AdamW moves it by round-off alone).
 
-Each cell's limits are in ``bench/limits/<cell>.json``, with the readings
-they were set from.
+A cell that runs every stage of a deployment has the three numbers of each
+stage, named ``s<k>.loss_gap`` and so on.  Each cell's limits are in
+``bench/limits/<cell>.json``, with the readings they were set from.
 """
 from __future__ import annotations
 
@@ -84,28 +85,28 @@ def moved_leaves(ref: dict):
     return [n for n, g in ref["grad"].items() if g >= MOVED * med]
 
 
-def load_limits(path: str) -> Tuple[Dict[str, Optional[float]], Optional[list]]:
-    """({number: limit}, the steps whose losses count or None); a number
-    given no limit is reported, not judged."""
+def load_limits(path: str, prefixes=("",)
+                ) -> Tuple[Dict[str, Optional[float]], Dict[str, Optional[list]]]:
+    """({number: limit}, {prefix: the steps whose losses count or None})
+    for the numbers of each stage prefix ("" for a one-stage cell, ``s<k>.``
+    for stage k of a cell that runs every stage); a number given no limit
+    is reported, not judged."""
     if not os.path.exists(path):
         raise FileNotFoundError(f"no limits file {path}")
     with open(path) as f:
         raw = json.load(f)
-    return ({n: raw[n].get("limit") for n in NUMBERS if n in raw},
-            raw.get("loss_gap", {}).get("steps"))
+    return ({p + n: raw[p + n].get("limit") for p in prefixes
+             for n in NUMBERS if p + n in raw},
+            {p: raw.get(p + "loss_gap", {}).get("steps") for p in prefixes})
 
 
 def judge(nums: Dict[str, Tuple[float, str]],
           limits: Dict[str, Optional[float]]) -> Tuple[bool, Dict[str, dict]]:
     """(every judged number within its limit, {number: value and limit})."""
     ok, report = True, {}
-    for n in NUMBERS:
-        value, where = nums[n]
+    for n, (value, where) in nums.items():
         lim = limits.get(n)
-        if lim is None:
-            report[n] = {"value": value, "limit": None, "at": where}
-            continue
-        within = value <= lim          # False for NaN
-        ok = ok and within
         report[n] = {"value": value, "limit": lim, "at": where}
+        if lim is not None:
+            ok = ok and value <= lim       # False for NaN
     return ok, report

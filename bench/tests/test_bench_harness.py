@@ -55,15 +55,17 @@ def test_traced_run_reports_per_layer_metrics_and_breakdown(root):
 
 
 def test_a_cell_configuration_and_metric_added_as_files(root, tmp_path):
-    """A new configuration, traffic, limits and per-layer metric are new
-    files plus entries in BENCHMARK.json; no module of the harness is
-    edited."""
+    """A new model family, configuration, traffic, limits and per-layer
+    metric are new files plus entries in BENCHMARK.json; no module of the
+    harness is edited.  The family (``probe``, which only the test tree
+    holds) is named by the configuration's ``"family"``, and the metrics
+    count with its numbers."""
     import shutil
     new = str(tmp_path / "root")
     shutil.copytree(root, new)
     bench = harness.load_benchmark(new)
     cfg = dict(json.load(open(os.path.join(new, "bench/configs/tiny-qwen.json"))),
-               name="tiny-qwen-wide", hidden_size=96)
+               name="tiny-qwen-wide", hidden_size=96, family="probe")
     json.dump(cfg, open(os.path.join(new, "bench/configs/tiny-qwen-wide.json"),
                         "w"))
     json.dump({"stage": 1, "batch": 2, "seq": 64, "pool": 8, "branch": 4,
@@ -88,6 +90,49 @@ def test_a_cell_configuration_and_metric_added_as_files(root, tmp_path):
     r = run(new, cell, SEEDS[0], trace=True)
     assert r["metrics"]["steps_seen"]["value"] == r["attempted"]
     assert set(r["check"]) == set(tiny.NUMBERS)
+    r = run(new, cell, SEEDS[0])
+    assert r["correct"], r["check"]
+    m = r["metrics"]
+    assert set(m) == {"train_tokens_per_s", "mfu", "setup_s"}
+    # mfu counts the family's FLOPs a step: 123456789 over 2 x 64 tokens
+    from bench.tests import probe_family
+    assert m["mfu"]["value"] == pytest.approx(
+        100 * probe_family.FLOPS_PER_STEP * m["train_tokens_per_s"]["value"]
+        / (2 * 64) / tiny.PEAK["bf16_flops_per_s"])
+
+
+def test_configuration_naming_an_unknown_family_fails(root, tmp_path):
+    import shutil
+    new = str(tmp_path / "root")
+    shutil.copytree(root, new)
+    path = os.path.join(new, "bench/configs/tiny-glm.json")
+    json.dump(dict(json.load(open(path)), family="no_such_family"),
+              open(path, "w"))
+    with pytest.raises(ValueError, match="no model family 'no_such_family'"):
+        run(new, "tiny-glm.stage_sil", SEEDS[0])
+    json.dump({k: v for k, v in json.load(open(path)).items()
+               if k != "family"}, open(path, "w"))
+    with pytest.raises(ValueError, match="names no \"family\""):
+        run(new, "tiny-glm.stage_sil", SEEDS[0])
+
+
+# the tiny qwen cells' numbers at SEEDS[0], read before the dense family
+# was moved behind the family contract: the move changes no reading
+BEFORE = {
+    "tiny-qwen.stage0_sil": {"loss_gap": 4.4732224745095796e-05,
+                             "grad_gap": 0.001616613901737397,
+                             "change_gap": 0.003557526835196843},
+    "tiny-qwen.stage_last_ce": {"loss_gap": 2.1598101835392982e-05,
+                                "grad_gap": 0.004611403614382444,
+                                "change_gap": 0.0014510109100782818},
+}
+
+
+@pytest.mark.parametrize("cell", sorted(BEFORE))
+def test_tiny_cells_read_as_before_through_the_dense_family(root, cell):
+    r = run(root, cell, SEEDS[0])
+    got = {n: c["value"] for n, c in r["check"].items()}
+    assert got == pytest.approx(BEFORE[cell], rel=1e-6)
 
 
 def test_unknown_cell_and_missing_files_fail(root, tmp_path):
@@ -115,16 +160,8 @@ def test_command_without_chip_exits_nonzero_with_no_result():
 
 
 def _tiny_run(root):
-    from bench import model as bm
-    from bench.stage import StageRun
-    from bench.traffic import load_traffic
-    bench = harness.load_benchmark(root)
-    cell = harness.workload(bench, "tiny-glm.stage_sil")
-    cfg = bm.load_config(os.path.join(
-        root, harness.config_entry(bench, cell["config"])["file"]))
-    traffic = load_traffic(os.path.join(
-        root, "bench/traffic", cell["traffic"] + ".json"), cell["traffic"])
-    return StageRun(cfg, traffic, SEEDS[0])
+    cell = harness.load_cell(root, "tiny-glm.stage_sil")
+    return cell.family.StageRun(cell.cfg, cell.traffic, SEEDS[0])
 
 
 def test_step_temporaries_from_the_builders_program(root):
@@ -133,7 +170,7 @@ def test_step_temporaries_from_the_builders_program(root):
     timed arguments."""
     run = _tiny_run(root)
     run.first_steps()
-    assert run.temp_bytes() > 0
+    assert run.temp_bytes()[0] > 0
 
 
 def test_step_temporaries_without_the_builders_program_is_an_error(root):

@@ -7,16 +7,15 @@ import os
 import jax
 import pytest
 
-from bench import harness
-from bench import model as bm
+from bench import common, harness
 
 ROOT = harness.ROOT
 BENCH = harness.load_benchmark(ROOT)
+bm = harness.load_family(ROOT, "dense")
 
 
 def cfg_of(name):
-    return bm.load_config(os.path.join(
-        ROOT, harness.config_entry(BENCH, name)["file"]))
+    return harness.load_config(ROOT, harness.config_entry(BENCH, name))[1]
 
 
 QWEN, GLM = cfg_of("qwen2-1.5b"), cfg_of("chatglm3-6b")
@@ -68,7 +67,7 @@ def test_kernel_costs_by_hand():
 
 def test_stage_bounds_match_the_program():
     from repro.core.partition import make_plan
-    from bench.stage import program_config
+    program_config = bm.program_config
     for cfg in (QWEN, GLM):
         assert list(make_plan(program_config(cfg), cfg.stages).bounds) == \
             cfg.stage_bounds()
@@ -82,8 +81,7 @@ def test_layout_matches_the_program(cfg, stage):
     step takes: same paths, shapes and float32 dtype."""
     from repro.core import partition
     from repro.models import model as M
-    from bench.stage import program_config
-    mc = program_config(cfg)
+    mc = bm.program_config(cfg)
     plan = partition.make_plan(mc, cfg.stages)
     prog = jax.eval_shape(lambda: partition.slice_stage_params(
         mc, plan, M.init_params(mc, jax.random.PRNGKey(0)), stage))
@@ -101,7 +99,9 @@ def test_config_files_agree_with_the_benchmark():
         assert raw["name"] == entry["name"]
         assert raw["source"] == entry["source"]
         assert sorted(raw["reduced"]) == sorted(entry["reduced"])
-        assert set(raw["keys"]) == set(bm.GENERIC)
+        assert harness.load_config(ROOT, entry)[1].name == entry["name"]
+        if raw["family"] == "dense":
+            assert set(raw["keys"]) == set(bm.GENERIC)
 
 
 def test_departure_runs_while_the_published_number_stays():
@@ -112,13 +112,13 @@ def test_departure_runs_while_the_published_number_stays():
         raw = json.load(f)
     assert raw["rms_norm_eps"] == 1e-6 and not raw["reduced"]
     assert raw["departs"]["rms_norm_eps"]["run_at"] == 1e-5
-    assert bm.load_config(path).norm_eps == 1e-5
+    assert bm.parse(raw, path).norm_eps == 1e-5
 
 
 def test_seed_keys_distinct_past_32_bits():
-    a = jax.random.key_data(bm.seed_keys(5)[0])
-    b = jax.random.key_data(bm.seed_keys(5 + 2 ** 32)[0])
-    c = jax.random.key_data(bm.seed_keys(5)[0])
+    a = jax.random.key_data(common.seed_keys(5)[0])
+    b = jax.random.key_data(common.seed_keys(5 + 2 ** 32)[0])
+    c = jax.random.key_data(common.seed_keys(5)[0])
     assert (a != b).any() and (a == c).all()
 
 

@@ -90,3 +90,28 @@ def test_reduce_trace_without_device_plane_fails(tmp_path):
     jax.profiler.stop_trace()
     with pytest.raises(RuntimeError, match="plane"):
         tr.reduce_trace(str(tmp_path))
+
+
+def test_roofline_reads_the_family_costs_over_the_chips():
+    """``trace.roofline`` (the kernel readers): the least time of the
+    kernel's calls from the family's per-call cost over their device time,
+    over every chip; silent where no call ran or the family has no cost."""
+    from types import SimpleNamespace
+    ms = 1e6
+    peak = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
+
+    def chip(n_calls, each_ms):
+        dev = [(FLASH.replace(".18", f".{i}"), 10 * i * ms,
+                (10 * i + each_ms) * ms) for i in range(n_calls)]
+        return tr.reduce_events(dev, [], (0, 100 * ms))
+    # one call: 4e9 FLOPs (4 ms at peak) and 2e8 bytes (2 ms): 4 ms least
+    costs = {"flash_attention_tpu": (4e9, 2e8)}
+    m = SimpleNamespace(traces=[chip(2, 8), chip(1, 5)], peak=peak,
+                        kernel_costs=[costs, costs])
+    assert tr.roofline(m, "flash_attention_tpu") == pytest.approx(
+        100 * 3 * 4 / (2 * 8 + 5))
+    assert tr.roofline(m, "sil_mse_fwd_tpu") is None
+    m.kernel_costs = [{}, {}]
+    assert tr.roofline(m, "flash_attention_tpu") is None
+    m.traces = None
+    assert tr.roofline(m, "flash_attention_tpu") is None

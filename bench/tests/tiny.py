@@ -1,6 +1,7 @@
 """A benchmark root at a size a CPU test run can hold: the real benchmark's
 files, plus configurations cut from the real ones (the same key mappings and
-training settings at a few dozen wide), their traffic and their limits."""
+training settings at a few dozen wide), their traffic and their limits, and
+a model family that only the test tree holds."""
 import json
 import os
 import shutil
@@ -26,11 +27,26 @@ SHRINK = {
                     "kv_channels": 16, "ffn_hidden_size": 96,
                     "padded_vocab_size": 384},
 }
-STAGES = {"qwen2-1.5b": 2, "chatglm3-6b": 3}
-# (tiny cell, configuration, traffic, stage)
-CELLS = (("tiny-qwen.stage0_sil", "qwen2-1.5b", "tiny_stage0", 0),
-         ("tiny-qwen.stage_last_ce", "qwen2-1.5b", "tiny_last", -1),
-         ("tiny-glm.stage_sil", "chatglm3-6b", "tiny_stage1", 1))
+# tiny configuration: (the real one it is cut from, its stages)
+CONFIGS = {"tiny-qwen": ("qwen2-1.5b", 2), "tiny-glm": ("chatglm3-6b", 3),
+           "tiny-qwen4": ("qwen2-1.5b", 4)}
+# (tiny cell, configuration, traffic, stage, the real cell it stands for)
+CELLS = (("tiny-qwen.stage0_sil", "tiny-qwen", "tiny_stage0", 0,
+          "qwen2-1.5b.stage0_sil"),
+         ("tiny-qwen.stage_last_ce", "tiny-qwen", "tiny_last", -1,
+          "qwen2-1.5b.stage_last_ce"),
+         ("tiny-glm.stage_sil", "tiny-glm", "tiny_stage1", 1,
+          "chatglm3-6b.stage_sil"))
+# every stage at once, one per chip: a run needs 4 devices
+DEPLOYMENT = ("tiny-qwen4.fig5", "tiny-qwen4", "tiny_all", "all",
+              "qwen2-1.5b.fig5_4chip")
+TEST_FAMILY = os.path.join(os.path.dirname(__file__), "probe_family.py")
+
+
+def write_limits(root: str, cell: str, prefixes=("",), limits=None):
+    with open(os.path.join(root, "bench/limits", cell + ".json"), "w") as f:
+        json.dump({p + n: {"limit": (limits or LIMITS)[n]}
+                   for p in prefixes for n in NUMBERS}, f)
 
 
 def make_root(dst: str, limits=None) -> str:
@@ -38,39 +54,38 @@ def make_root(dst: str, limits=None) -> str:
     shutil.copytree(os.path.join(harness.ROOT, "bench"),
                     os.path.join(dst, "bench"),
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    shutil.copy(TEST_FAMILY, os.path.join(dst, "bench/families/probe.py"))
     bench = harness.load_benchmark(harness.ROOT)
     configs = []
-    for real, cut in SHRINK.items():
+    for name, (real, stages) in CONFIGS.items():
         entry = harness.config_entry(bench, real)
         with open(os.path.join(harness.ROOT, entry["file"])) as f:
             raw = json.load(f)
-        name = {"qwen2-1.5b": "tiny-qwen", "chatglm3-6b": "tiny-glm"}[real]
-        raw.update(cut, name=name)
-        raw["deployment"]["stages"] = STAGES[real]
+        raw.update(SHRINK[real], name=name)
+        raw["deployment"]["stages"] = stages
         path = f"bench/configs/{name}.json"
         with open(os.path.join(dst, path), "w") as f:
             json.dump(raw, f)
         configs.append(dict(entry, name=name, file=path))
     bench["configs"] = configs
     bench["workloads"] = []
-    for cell, real, traffic, stage in CELLS:
+    for cell, config, traffic, stage, _ in CELLS + (DEPLOYMENT,):
         with open(os.path.join(dst, "bench/traffic", traffic + ".json"),
                   "w") as f:
             json.dump({"stage": stage, "batch": 2, "seq": 64, "pool": 8,
                        "branch": 4, "repeat_p": 0.1, "span": 8,
                        "check_steps": 3}, f)
-        with open(os.path.join(dst, "bench/limits", cell + ".json"), "w") as f:
-            json.dump({n: {"limit": (limits or LIMITS)[n]} for n in NUMBERS},
-                      f)
-        config = {"qwen2-1.5b": "tiny-qwen", "chatglm3-6b": "tiny-glm"}[real]
+        stages = CONFIGS[config][1]
+        write_limits(dst, cell, [f"s{k}." for k in range(stages)]
+                     if stage == "all" else [""], limits)
         bench["workloads"].append({"name": cell, "config": config,
-                                   "traffic": traffic, "chips": 1,
+                                   "traffic": traffic,
+                                   "chips": stages if stage == "all" else 1,
                                    "why": "a CPU test at a tiny size"})
-    cells = [c[0] for c in CELLS]
     for m in bench["per_layer"] + bench["end_to_end"]:
         if "workloads" in m:
-            m["workloads"] = cells if m["name"] != "sil_mse_roofline" \
-                else [c for c in cells if "last" not in c]
+            m["workloads"] = [c[0] for c in CELLS + (DEPLOYMENT,)
+                              if c[4] in m["workloads"]]
     with open(os.path.join(dst, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return dst

@@ -168,3 +168,45 @@ def reduce_trace(logdir: str, device_plane: str = DEVICE_PLANE,
         raise RuntimeError(f"no {device_plane!r} plane in the trace")
     return [reduce_events(devices[name], spans, windows[0])
             for name in sorted(devices)]
+
+
+# --------------------------------------------------------------------------
+# what the readers and the result line take from the reduced traces
+# --------------------------------------------------------------------------
+
+def roofline(m, kernel: str) -> Optional[float]:
+    """Percent of its roofline a kernel ran at over the window, on the
+    cell's chips together: the least time its calls could take on the chip
+    (per call the larger of its FLOPs over the peak and its bytes over the
+    bandwidth, ``m.kernel_costs``) over the device time of its events,
+    found by the kernel's name.  None without a trace, a call, or the
+    family's cost of the kernel."""
+    if not m.traces:
+        return None
+    least = secs = 0.0
+    for t, costs in zip(m.traces, m.kernel_costs):
+        calls, s = t.kernel(kernel)
+        if calls == 0 or s <= 0:
+            continue
+        if kernel not in costs:
+            return None
+        flops, nbytes = costs[kernel]
+        least += calls * max(flops / m.peak["bf16_flops_per_s"],
+                             nbytes / m.peak["hbm_bytes_per_s"])
+        secs += s
+    return 100.0 * least / secs if secs > 0 else None
+
+
+def breakdown(traces: Sequence[Reduced], n: int = 10) -> dict:
+    """The result line's ``breakdown``: the device operations that took
+    most time and the longest idle gaps, ``n`` of each; over several chips
+    each name starts with its chip (``chip2 fusion.12``)."""
+    if len(traces) == 1:
+        return {"device_ops": traces[0].top_ops(n),
+                "idle_gaps": [[g, s] for g, s in traces[0].gaps[:n]]}
+    ops = [[f"chip{c} {name}", s] for c, t in enumerate(traces)
+           for name, s in t.top_ops(n)]
+    gaps = [[f"chip{c} {g}", s] for c, t in enumerate(traces)
+            for g, s in t.gaps[:n]]
+    return {"device_ops": sorted(ops, key=lambda x: -x[1])[:n],
+            "idle_gaps": sorted(gaps, key=lambda x: -x[1])[:n]}

@@ -1,5 +1,6 @@
-"""Traffic of a training cell: which stage of the deployment runs, at what
-batch and sequence length, and the token batches it is fed.
+"""Traffic of a training cell: which stage of the deployment runs (or all of
+them, one per chip), at what batch and sequence length, and the token
+batches it is fed.
 
 A traffic file (``bench/traffic/<name>.json``) holds only parameters; this
 one generator reads them.  Tokens follow the program's synthetic corpus
@@ -12,6 +13,7 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass
+from typing import List, Union
 
 import numpy as np
 
@@ -19,7 +21,7 @@ import numpy as np
 @dataclass(frozen=True)
 class Traffic:
     name: str
-    stage: int          # stage index; negative counts from the last
+    stage: Union[int, str]   # stage index, negative from the last; "all"
     batch: int
     seq: int
     pool: int           # distinct sequences generated; step i takes rows
@@ -28,12 +30,20 @@ class Traffic:
     span: int           # longest induction span
     check_steps: int    # first steps compared with the reference
 
-    def stage_index(self, n_stages: int) -> int:
+    @property
+    def deployment(self) -> bool:
+        """Every stage of the deployment at once, stage k on chip k."""
+        return self.stage == "all"
+
+    def stages(self, n_stages: int) -> List[int]:
+        """The stages the cell runs: all of them, or the one it names."""
+        if self.deployment:
+            return list(range(n_stages))
         k = self.stage if self.stage >= 0 else n_stages + self.stage
         if not 0 <= k < n_stages:
             raise ValueError(f"traffic {self.name}: stage {self.stage} out of "
                              f"range for {n_stages} stages")
-        return k
+        return [k]
 
 
 def load_traffic(path: str, name: str) -> Traffic:
@@ -42,6 +52,9 @@ def load_traffic(path: str, name: str) -> Traffic:
     t = Traffic(name=name, **{k: raw[k] for k in (
         "stage", "batch", "seq", "pool", "branch", "repeat_p", "span",
         "check_steps")})
+    if not (isinstance(t.stage, int) or t.deployment):
+        raise ValueError(f"traffic {name}: stage {t.stage!r} is neither a "
+                         "stage index nor \"all\"")
     if t.pool < t.batch * t.check_steps:
         raise ValueError(f"traffic {name}: pool {t.pool} cannot give "
                          f"{t.check_steps} checked steps distinct rows")
